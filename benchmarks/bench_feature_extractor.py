@@ -15,6 +15,7 @@ import jax.numpy as jnp
 from benchmarks.common import row, time_fn
 from repro.core.feature_extractor import ExtractorConfig, FeatureExtractor
 from repro.data.packets import PacketTraceConfig, synth_packet_trace
+from repro.runtime import RuntimeConfig
 
 
 def run() -> list[str]:
@@ -44,7 +45,9 @@ def run() -> list[str]:
         jax.tree.map(lambda x: x[i], packets), jnp.int32(0)))(jnp.arange(n))
     init = jnp.zeros((8192, 16), jnp.int32)
     prog = default_program()
-    kern_fn = jax.jit(lambda s, m, st: flow_feature_update(prog, s, m, st, block=256))
+    interp = RuntimeConfig().interpret
+    kern_fn = jax.jit(lambda s, m, st: flow_feature_update(prog, s, m, st, block=256,
+                                                           interpret=interp))
     t_kern = time_fn(kern_fn, slots, meta, init, warmup=1, iters=2)
     rows.append(row("feature_extractor_pallas_interpret", t_kern * 1e6,
                     f"mpkt_s={n/t_kern/1e6:.3f};note=interpret-mode-correctness-only"))

@@ -9,16 +9,18 @@ import numpy as np
 
 from benchmarks.common import row, time_fn
 from repro.launch.mesh import HBM_BW, PEAK_FLOPS_BF16
+from repro.runtime import RuntimeConfig
 
 
 def run() -> list[str]:
     rows = []
+    interp = RuntimeConfig().interpret
     from repro.kernels.arype_matmul import arype_matmul, ref_matmul
 
     for m, k, n in [(1024, 1024, 1024), (4096, 512, 2048)]:
         x = jax.random.normal(jax.random.PRNGKey(0), (m, k), jnp.float32)
         w = jax.random.normal(jax.random.PRNGKey(1), (k, n), jnp.float32)
-        err = float(jnp.abs(arype_matmul(x, w) - ref_matmul(x, w)).max())
+        err = float(jnp.abs(arype_matmul(x, w, interpret=interp) - ref_matmul(x, w)).max())
         t = time_fn(jax.jit(lambda a, b: a @ b), x, w)
         flops = 2 * m * k * n
         byts = (m * k + k * n + m * n) * 2  # bf16 target
@@ -32,7 +34,7 @@ def run() -> list[str]:
 
     x = jax.random.normal(jax.random.PRNGKey(0), (20000, 3), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (3, 32), jnp.float32)
-    err = float(jnp.abs(vpe_matmul(x, w) - ref_vpe_matmul(x, w)).max())
+    err = float(jnp.abs(vpe_matmul(x, w, interpret=interp) - ref_vpe_matmul(x, w)).max())
     t = time_fn(jax.jit(lambda a, b: (a[:, :, None] * b[None]).sum(1)), x, w)
     rows.append(row("vpe_smallmm_20000x3x32", t * 1e6,
                     f"max_err={err:.1e};note=paper_cnn_layer1_f1000"))
@@ -43,7 +45,7 @@ def run() -> list[str]:
     q = jax.random.normal(jax.random.PRNGKey(0), (b, h, s, d), jnp.float32)
     k = jax.random.normal(jax.random.PRNGKey(1), (b, h, s, d), jnp.float32)
     v = jax.random.normal(jax.random.PRNGKey(2), (b, h, s, d), jnp.float32)
-    out = flash_attention(q, k, v, mask="causal")
+    out = flash_attention(q, k, v, mask="causal", interpret=interp)
     ref = ref_attention(q.reshape(b * h, s, d), k.reshape(b * h, s, d),
                         v.reshape(b * h, s, d), mask="causal")
     err = float(jnp.abs(out.reshape(b * h, s, d) - ref).max())
@@ -59,7 +61,7 @@ def run() -> list[str]:
     meta = jnp.asarray(rng.integers(0, 1000, (4096, META_WIDTH)), jnp.int32)
     init = jnp.zeros((8192, 16), jnp.int32)
     prog = default_program()
-    outk = flow_feature_update(prog, slots, meta, init)
+    outk = flow_feature_update(prog, slots, meta, init, interpret=interp)
     refk = ref_flow_feature_update(prog, slots, meta, init)
     eq = bool(jnp.all(outk == refk))
     rows.append(row("flow_features_4096pkts", 0.0, f"exact_match={eq}"))

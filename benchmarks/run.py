@@ -80,6 +80,7 @@ def main(argv: list[str] | None = None) -> int:
 
     from repro.runtime import RuntimeConfig, current_runtime, octopus_runtime, platform
 
+    platform.enable_compile_cache()
     ctx = (octopus_runtime(RuntimeConfig.calibrated()) if args.calibrated
            else contextlib.nullcontext())
     suites = _suites(args.smoke)

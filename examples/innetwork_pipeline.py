@@ -40,8 +40,11 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.runtime import platform
+
 
 def main():
+    platform.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--flows", type=int, default=400)
     ap.add_argument("--steps", type=int, default=40,

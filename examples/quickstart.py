@@ -17,11 +17,12 @@ from repro.configs import get_config, reduced_config
 from repro.data.tokens import TokenPipeline, TokenPipelineConfig
 from repro.models import LM
 from repro.optim import adamw
-from repro.runtime import RoutePlan
+from repro.runtime import RoutePlan, platform
 from repro.train.steps import make_train_step
 
 
 def main():
+    platform.enable_compile_cache()
     cfg = reduced_config(get_config("qwen3-0.6b"))
     model = LM(cfg)
     params = model.init(jax.random.PRNGKey(0))

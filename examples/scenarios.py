@@ -24,6 +24,7 @@ from repro.scenarios import (
     HeavyHitterScenario,
     adversarial_config,
 )
+from repro.runtime import platform
 from repro.serving import OctopusPipeline, PipelineConfig
 
 
@@ -85,6 +86,7 @@ def adversarial(steps: int) -> None:
 
 
 def main(argv=None) -> int:
+    platform.enable_compile_cache()
     ap = argparse.ArgumentParser(description="scenario family demo")
     ap.add_argument("--steps", type=int, default=16)
     args = ap.parse_args(argv)

@@ -13,10 +13,12 @@ import numpy as np
 
 from repro.configs import get_config, reduced_config
 from repro.models import LM
+from repro.runtime import platform
 from repro.serving import Request, ServeConfig, ServeEngine
 
 
 def main():
+    platform.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen3-0.6b")
     ap.add_argument("--requests", type=int, default=6)

@@ -24,8 +24,11 @@ sys.path.insert(0, "src")
 
 import jax
 
+from repro.runtime import platform
+
 
 def main():
+    platform.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--requests", type=int, default=16,
                     help="closed-loop microbatches per client")

@@ -15,6 +15,7 @@ sys.path.insert(0, "src")
 
 from repro.configs import get_config
 from repro.data.tokens import TokenPipelineConfig
+from repro.runtime import platform
 from repro.train.loop import Trainer, TrainLoopConfig
 
 
@@ -37,6 +38,7 @@ def size_cfg(size: str):
 
 
 def main():
+    platform.enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--size", default="small", choices=["small", "20m", "100m"])
     ap.add_argument("--steps", type=int, default=200)

@@ -25,7 +25,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh
 from jax.sharding import PartitionSpec as P
-from jax.experimental.shard_map import shard_map
 
 
 def pipeline_forward(
@@ -91,8 +90,8 @@ def pipeline_forward(
         jax.tree.map(lambda _: P(axis), stage_params),
         P(),  # microbatch stream replicated along the pipeline axis
     )
-    fn = shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
-                   check_rep=False)
+    fn = jax.shard_map(body, mesh=mesh, in_specs=in_specs, out_specs=P(),
+                       check_vma=False)
     return fn(stage_params, x_microbatches)
 
 

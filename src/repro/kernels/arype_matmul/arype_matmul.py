@@ -90,7 +90,7 @@ def mm_fused(
     bk: int = 128,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """x: (M, K) @ w: (K, N) -> (M, N).  Dims must be multiples of the blocks
     (ops.py pads).  ``interpret=True`` on CPU; on a real TPU pass False."""
@@ -124,7 +124,7 @@ def mm_fused_q(
     bk: int = 128,
     activation: str = "none",
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Int8 x_q: (M, K) @ w_q: (K, N) -> f32-ish (M, N), int32 accumulation.
 
@@ -160,7 +160,7 @@ def mm_unfused_partials(
     bm: int = 128,
     bn: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Returns partial blocks (K/bk, M, N) in fp32 — the 'wo/ collaborating'
     ablation where block aggregation is a separate HBM pass."""

@@ -36,7 +36,7 @@ def arype_matmul(
     *,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """(M, K) @ (K, N) with fused K-block accumulation (collaborative mode)."""
     m, k = x.shape
@@ -61,7 +61,7 @@ def arype_matmul_q(
     scale_w,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Quantized (M, K) @ (K, N): f32 operands clip-rounded to symmetric int8
     on the given per-layer scales (``scale_w`` a float or a per-output-channel
@@ -91,7 +91,7 @@ def arype_matmul_unfused(
     *,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """'wo/ collaborating' ablation: partial K-blocks written to HBM, then a
     separate aggregation pass (paper Table 6 baseline)."""

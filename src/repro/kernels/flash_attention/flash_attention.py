@@ -90,7 +90,7 @@ def flash_fwd(
     bq: int = 128,
     bk: int = 128,
     scale: float | None = None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     bh, sq, d = q.shape
     _, sk, _ = k.shape

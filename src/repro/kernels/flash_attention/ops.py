@@ -23,7 +23,7 @@ def flash_attention(
     kv_len: int | None = None,
     bq: int = 128,
     bk: int = 128,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     b, hq, sq, d = q.shape
     _, hkv, sk, _ = k.shape

@@ -4,11 +4,26 @@ The FPGA feature extractor keeps an 8k-entry flow-state table; for each packet
 a 16-lane ALU cluster folds the packet's *meta register* into the flow's
 *history register* with per-lane micro-ops {nop, wr, add, sub, max, min, inc}.
 
-TPU adaptation: the whole flow-state table (8192 x 16 int32 = 512 KB) is VMEM
-resident; packets stream through the grid in blocks; within a block the kernel
-walks packets with ``fori_loop`` (updates to the same flow must be ordered —
-this is the inherently sequential part the FPGA pipelines at line rate).  The
-16 feature lanes update vectorized, mirroring the 16 parallel ALUs.
+TPU adaptation: the whole flow-state table (8192 x 16 int32) is copied into a
+VMEM scratch once per call and written back once at the end (the HBM buffer
+is aliased in place); packets stream through the grid in blocks; within a
+block the kernel walks packets with ``fori_loop`` (updates to the same flow
+must be ordered — this is the inherently sequential part the FPGA pipelines
+at line rate).  The 16 feature lanes update vectorized, mirroring the 16
+parallel ALUs.
+
+What the TPU compiler (Mosaic) accepts shapes the kernel:
+
+  * the per-packet slot index is a scalar, so the slot vector is scalar-
+    prefetched into SMEM (a scalar read out of a VMEM vector is refused);
+  * the meta-source selection (``meta[program[:, 1]]``) does not depend on
+    the flow state, so it is gathered for the whole batch in XLA before the
+    kernel, which then reads one (1, 16) operand row per packet;
+  * the history-source selection (``hist[program[:, 2]]``) does depend on
+    the state and is expressed as 16 lane-broadcast selects — Mosaic lowers
+    no in-kernel gather and no int32 lane reduction;
+  * the opcode dispatch is a chain of ``where`` (``jnp.select`` lowers
+    through an argmax, which Mosaic supports only in float32).
 
 Micro-op encoding per lane j (program row j = [opcode, meta_src, hist_src]):
   0 nop : out = hist[hist_src]
@@ -27,6 +42,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 N_LANES = 16
 
@@ -44,22 +60,47 @@ def apply_alu_program(program: jax.Array, meta: jax.Array, hist: jax.Array) -> j
     ).astype(jnp.int32)
 
 
-def _flow_kernel(program_ref, slots_ref, meta_ref, init_state_ref, state_ref, *, block: int):
-    @pl.when(pl.program_id(0) == 0)
-    def _init():
-        state_ref[...] = init_state_ref[...]
+def _alu_lanes(opcode: jax.Array, hist_src: jax.Array, a: jax.Array,
+               hist: jax.Array) -> jax.Array:
+    """:func:`apply_alu_program` on (1, 16) rows, in the ops Mosaic lowers:
+    ``a`` is the pre-gathered meta operand row, the history source is picked
+    by lane-broadcast selects and the opcode by a ``where`` chain."""
+    b = hist
+    for k in range(N_LANES):
+        b = jnp.where(hist_src == k, jnp.broadcast_to(hist[:, k:k + 1], hist.shape), b)
+    out = b  # nop (and any unknown opcode)
+    for code, val in ((1, a), (2, b + a), (3, b - a), (4, jnp.maximum(b, a)),
+                      (5, jnp.minimum(b, a)), (6, b + 1)):
+        out = jnp.where(opcode == code, val, out)
+    return out
 
-    program = program_ref[...]
 
-    def body(i, _):
-        slot = slots_ref[i]
-        hist = pl.load(state_ref, (pl.dslice(slot, 1), slice(None)))[0]
-        meta = meta_ref[i, :]
-        new = apply_alu_program(program, meta, hist)
-        pl.store(state_ref, (pl.dslice(slot, 1), slice(None)), new[None, :])
-        return 0
+def _flow_kernel(slots_ref, ctrl_ref, a_ref, state_hbm, out_hbm, table, sem, *,
+                 block: int, n_blocks: int):
+    j = pl.program_id(0)
+
+    @pl.when(j == 0)
+    def _load():
+        copy = pltpu.make_async_copy(state_hbm, table, sem)
+        copy.start()
+        copy.wait()
+
+    opcode = ctrl_ref[0:1, :]
+    hist_src = ctrl_ref[1:2, :]
+
+    def body(i, carry):
+        row = pl.ds(slots_ref[j * block + i], 1)
+        table[row, :] = _alu_lanes(opcode, hist_src, a_ref[pl.ds(i, 1), :],
+                                   table[row, :])
+        return carry
 
     lax.fori_loop(0, block, body, 0)
+
+    @pl.when(j == n_blocks - 1)
+    def _store():
+        copy = pltpu.make_async_copy(table, out_hbm, sem)
+        copy.start()
+        copy.wait()
 
 
 def flow_update(
@@ -68,23 +109,33 @@ def flow_update(
     meta: jax.Array,  # (P, M) int32 meta registers
     init_state: jax.Array,  # (F, 16) int32 flow-state table
     *,
-    block: int = 256,
-    interpret: bool = True,
+    block: int,
+    interpret: bool,
 ) -> jax.Array:
-    p, m_width = meta.shape
+    p = slots.shape[0]
     f = init_state.shape[0]
     assert p % block == 0, (p, block)
-    kernel = functools.partial(_flow_kernel, block=block)
+    n_blocks = p // block
+    a = jnp.take(meta, program[:, 1], axis=1)  # (P, 16) meta operand per lane
+    ctrl = jnp.stack([program[:, 0], program[:, 2]])  # (2, 16) opcode, hist_src
+    kernel = functools.partial(_flow_kernel, block=block, n_blocks=n_blocks)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1,
+        grid=(n_blocks,),
+        in_specs=[
+            pl.BlockSpec((2, N_LANES), lambda i, s: (0, 0)),
+            pl.BlockSpec((block, N_LANES), lambda i, s: (i, 0)),
+            pl.BlockSpec(memory_space=pl.ANY),
+        ],
+        out_specs=pl.BlockSpec(memory_space=pl.ANY),
+        scratch_shapes=[pltpu.VMEM((f, N_LANES), jnp.int32),
+                        pltpu.SemaphoreType.DMA(())],
+    )
     return pl.pallas_call(
         kernel,
-        grid=(p // block,),
-        in_specs=[
-            pl.BlockSpec((N_LANES, 3), lambda i: (0, 0)),
-            pl.BlockSpec((block,), lambda i: (i,)),
-            pl.BlockSpec((block, m_width), lambda i: (i, 0)),
-            pl.BlockSpec((f, N_LANES), lambda i: (0, 0)),
-        ],
-        out_specs=pl.BlockSpec((f, N_LANES), lambda i: (0, 0)),
+        grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((f, N_LANES), jnp.int32),
+        input_output_aliases={3: 0},  # (slots, ctrl, a, state): state -> out
+        compiler_params=pltpu.CompilerParams(dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(program, slots, meta, init_state)
+    )(slots, ctrl, a, init_state)

@@ -93,7 +93,7 @@ def fold_features(
     *,
     keep: jax.Array | None = None,
     block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fold a packet stream into a (F, 16) feature table through the Pallas
     ALU-cluster kernel, optionally dropping packets.
@@ -122,7 +122,7 @@ def flow_feature_update(
     init_state: jax.Array,
     *,
     block: int = 256,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Fold a packet stream into the flow-state table.  Pads the packet axis
     with no-op packets (slot pointing at a scratch row)."""

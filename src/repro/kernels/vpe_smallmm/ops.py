@@ -21,7 +21,7 @@ def vpe_matmul(
     *,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     m, k = x.shape
     _, n = w.shape
@@ -45,7 +45,7 @@ def vpe_matmul_q(
     scale_w,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Quantized VPE small-matmul: f32 operands clip-rounded to symmetric
     int8 on the per-layer scales (``scale_w`` a float or a per-output-channel

@@ -63,7 +63,7 @@ def vpe_mm(
     bm: int = 256,
     activation: str = "none",
     out_dtype=None,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """x: (M, K) @ w: (K, N), M a multiple of bm (ops.py pads), K*N small."""
     m, k = x.shape
@@ -91,7 +91,7 @@ def vpe_mm_q(
     bm: int = 256,
     activation: str = "none",
     out_dtype=jnp.float32,
-    interpret: bool = True,
+    interpret: bool,
 ) -> jax.Array:
     """Int8 x_q: (M, K) @ w_q: (K, N) with int32 accumulation; M a multiple
     of bm (ops.py pads — zero int8 pads are exact).  ``dequant`` is the

@@ -291,8 +291,7 @@ def main(argv: list[str] | None = None) -> int:
 
     fp = platform.fingerprint()
     print(f"[calibrate] platform: {platform.fingerprint_id(fp)} "
-          f"(pallas={'yes' if platform.pallas_available() else 'no'}, "
-          f"interpret_default={platform.interpret_default()})")
+          f"(interpret_default={platform.interpret_default()})")
     iters = args.iters if args.iters is not None else (2 if args.smoke else 5)
     grid = autotune.default_grid(smoke=args.smoke)
     print(f"[calibrate] sweeping {len(grid)} (m,k,n) shapes x 2 engine paths "

@@ -51,7 +51,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str,
     from repro.configs import get_config
     from repro.launch import mesh as meshmod
     from repro.launch.cells import build_cell
-    from repro.launch.roofline import analyze_compiled, cost_dict, parse_collectives
+    from repro.launch.roofline import analyze_compiled, parse_collectives
 
     mesh = meshmod.make_production_mesh(multi_pod=(mesh_kind == "multi"))
     chips = mesh.devices.size
@@ -74,7 +74,7 @@ def run_cell(arch: str, shape: str, mesh_kind: str, out_dir: str,
             acell = build_cell(arch, shape, mesh, pallas=pallas,
                                overrides=overrides, analysis_nsb=n)
             acomp, adt = _compile_cell(acell, mesh)
-            ca = cost_dict(acomp)
+            ca = acomp.cost_analysis()
             coll = parse_collectives(acomp.as_text(), chips)
             costs[n] = dict(
                 flops=float(ca.get("flops", 0.0)),

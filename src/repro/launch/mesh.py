@@ -13,17 +13,16 @@ from __future__ import annotations
 import jax
 
 
-def _axis_type_kwargs(n: int) -> dict:
-    # jax.sharding.AxisType only exists in newer JAX; the pinned version's
-    # make_mesh has no axis_types kwarg and defaults to the same semantics.
-    axis_type = getattr(jax.sharding, "AxisType", None)
-    return {"axis_types": (axis_type.Auto,) * n} if axis_type is not None else {}
+def auto_axes(n: int) -> tuple:
+    """``axis_types`` for an ``n``-axis mesh whose shardings the compiler
+    propagates (``jax.make_mesh`` would otherwise make every axis explicit)."""
+    return (jax.sharding.AxisType.Auto,) * n
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes, **_axis_type_kwargs(len(axes)))
+    return jax.make_mesh(shape, axes, axis_types=auto_axes(len(axes)))
 
 
 def make_lanes_mesh(num_lanes: int):
@@ -45,8 +44,8 @@ def make_host_mesh(data: int = 2, model: int = 4, pod: int = 0):
     """Small mesh for CPU integration tests (requires the host-device flag)."""
     if pod:
         return jax.make_mesh((pod, data, model), ("pod", "data", "model"),
-                             **_axis_type_kwargs(3))
-    return jax.make_mesh((data, model), ("data", "model"), **_axis_type_kwargs(2))
+                             axis_types=auto_axes(3))
+    return jax.make_mesh((data, model), ("data", "model"), axis_types=auto_axes(2))
 
 
 # TPU v5e hardware constants (roofline denominators)

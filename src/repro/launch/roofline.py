@@ -176,18 +176,9 @@ class Roofline:
         return d
 
 
-def cost_dict(compiled) -> dict:
-    """compiled.cost_analysis() returns a one-element list of dicts in the
-    pinned JAX (a bare dict in newer versions); normalize to a dict."""
-    ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):
-        ca = ca[0] if ca else {}
-    return ca
-
-
 def analyze_compiled(label: str, mesh_name: str, chips: int, compiled,
                      model_flops: float, compile_s: float, notes: str = "") -> Roofline:
-    ca = cost_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     flops = float(ca.get("flops", 0.0))
     byts = float(ca.get("bytes accessed", 0.0))

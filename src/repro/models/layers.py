@@ -20,7 +20,7 @@ from repro.configs.base import ArchConfig
 from repro.core import router
 from repro.distributed.act import shard_act
 from repro.models.spec import ParamSpec
-from repro.runtime import RuntimeConfig
+from repro.runtime import RuntimeConfig, resolve_config
 
 NEG_INF = -1e30
 
@@ -189,7 +189,7 @@ def attention_core(
         mask = {"causal": "causal", "local": "local", "full": "full"}[kind]
         out = flash_attention(
             jnp.moveaxis(q, 2, 1), jnp.moveaxis(k, 2, 1), jnp.moveaxis(v, 2, 1),
-            mask=mask, window=window,
+            mask=mask, window=window, interpret=resolve_config(None).interpret,
         )
         return jnp.moveaxis(out, 1, 2)
     # For TP cleanliness, expand KV heads to the full head count (the repeated

@@ -62,9 +62,7 @@ def is_spec_leaf(x: Any) -> bool:
 
 def init_params(specs: Any, key: jax.Array) -> Any:
     """Materialize a spec tree into arrays, deterministically keyed by path."""
-    # jax.tree.flatten_with_path only exists in newer JAX; the pinned version
-    # exposes it via jax.tree_util.
-    flat, treedef = jax.tree_util.tree_flatten_with_path(specs, is_leaf=is_spec_leaf)
+    flat, treedef = jax.tree.flatten_with_path(specs, is_leaf=is_spec_leaf)
     leaves = []
     for path, spec in flat:
         pkey = fold_in_str(key, jax.tree_util.keystr(path))

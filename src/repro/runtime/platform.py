@@ -6,64 +6,55 @@ TPU/GPU, where every ``--use-pallas`` launch needed a manual
 ``runtime_overrides(interpret=False)``.  This module asks JAX what it is
 actually running on, once, and the answers become the config defaults.
 
-Probes are cached (the backend cannot change within a process) and never
-raise: an unimportable or uninitializable JAX degrades to conservative CPU
-answers, so this module is safe to use at config-construction time.
+Probes are cached (the backend cannot change within a process) and raise
+whatever JAX raises: a backend that fails to initialize is an error, never a
+silent answer of "cpu" that would turn Pallas interpret mode on behind the
+caller's back.
+
+:func:`enable_compile_cache` places JAX's persistent compilation cache: where
+``JAX_COMPILATION_CACHE_DIR`` is set JAX uses that directory, otherwise a
+fixed directory inside the checkout.
 """
 from __future__ import annotations
 
+import os
 from functools import lru_cache
+from pathlib import Path
 from typing import Dict
 
 # Backends where the Pallas kernels compile for real hardware; anything else
 # (cpu, interpreters, mocks) needs interpret mode.
 _ACCELERATOR_BACKENDS = frozenset({"tpu", "gpu", "cuda", "rocm"})
 
+# The checkout-local cache directory (listed in .gitignore).  A fixed path:
+# the cache key includes it, so a directory that moves never hits.
+CHECKOUT_CACHE_DIR = Path(__file__).resolve().parents[3] / ".jax_cache"
+
 
 @lru_cache(maxsize=None)
 def backend() -> str:
-    """The active JAX backend name ("cpu", "gpu", "tpu"); "cpu" on failure."""
-    try:
-        import jax
+    """The active JAX backend name ("cpu", "gpu", "tpu")."""
+    import jax
 
-        return jax.default_backend()
-    except Exception:
-        return "cpu"
+    return jax.default_backend()
 
 
 @lru_cache(maxsize=None)
 def device_kind() -> str:
-    """Hardware kind of device 0 (e.g. "cpu", "TPU v4"); "unknown" on failure."""
-    try:
-        import jax
+    """Hardware kind of device 0 (e.g. "cpu", "TPU v5 lite")."""
+    import jax
 
-        return jax.devices()[0].device_kind
-    except Exception:
-        return "unknown"
-
-
-@lru_cache(maxsize=None)
-def pallas_available() -> bool:
-    """Whether the Pallas engine kernels can be imported at all."""
-    try:
-        import jax.experimental.pallas  # noqa: F401
-
-        return True
-    except Exception:
-        return False
+    return jax.devices()[0].device_kind
 
 
 @lru_cache(maxsize=None)
 def device_count() -> int:
-    """Number of addressable local devices; 1 on failure.  Forced host
-    platforms (``--xla_force_host_platform_device_count``) count — that is
-    exactly how the lane tests/benchmarks exercise ``shard_map`` on CPU."""
-    try:
-        import jax
+    """Number of addressable local devices.  Forced host platforms
+    (``--xla_force_host_platform_device_count``) count — that is exactly how
+    the lane tests exercise ``shard_map`` on CPU."""
+    import jax
 
-        return jax.local_device_count()
-    except Exception:
-        return 1
+    return jax.local_device_count()
 
 
 def lanes_backend(num_lanes: int) -> str:
@@ -87,23 +78,34 @@ def interpret_default() -> bool:
     return not is_accelerator()
 
 
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; returns its directory.
+
+    If ``JAX_COMPILATION_CACHE_DIR`` is set, JAX already reads it and this
+    sets no other path.  Otherwise the cache lives at
+    :data:`CHECKOUT_CACHE_DIR`.  Every compile is cached, however short.
+    Call before the first compilation of the process."""
+    import jax
+
+    path = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(CHECKOUT_CACHE_DIR)
+    jax.config.update("jax_compilation_cache_dir", path)
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0.0)
+    return path
+
+
 def fingerprint() -> Dict[str, str]:
     """Identity of the execution platform, embedded in calibration artifacts
     so a cache written on one target is never silently applied to another."""
-    try:
-        import jax
+    import jax
 
-        jax_version = jax.__version__
-    except Exception:
-        jax_version = "unknown"
     return {
         "backend": backend(),
         "device_kind": device_kind(),
-        "jax": jax_version,
+        "jax": jax.__version__,
     }
 
 
 def fingerprint_id(fp: Dict[str, str] | None = None) -> str:
-    """Short one-line form of :func:`fingerprint` ("cpu/cpu/jax-0.4.37")."""
+    """Short one-line form of :func:`fingerprint` ("cpu/cpu/jax-0.9.0")."""
     fp = fp if fp is not None else fingerprint()
     return f"{fp['backend']}/{fp['device_kind']}/jax-{fp['jax']}"

@@ -54,7 +54,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 
 from repro.core import feature_extractor as fx
 from repro.core import flow_tracker as ft
@@ -155,7 +154,8 @@ class ShardedOctopusPipeline(OctopusPipeline):
             return jax.tree_util.tree_map(lambda x: x[None], out)
 
         spec = shd.lanes_spec()
-        return shard_map(body, mesh=self.mesh, in_specs=spec, out_specs=spec)
+        return jax.shard_map(body, mesh=self.mesh, in_specs=spec,
+                             out_specs=spec)
 
     def _merge_out(self, outs: PipelineStepOutput, src: jax.Array, *,
                    batch: Optional[int] = None) -> PipelineStepOutput:

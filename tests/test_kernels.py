@@ -24,7 +24,7 @@ def test_arype_matmul_sweep(m, k, n, dtype, act):
     kx, kw = jax.random.split(jax.random.PRNGKey(m * 1000 + k + n))
     x = jax.random.normal(kx, (m, k), dtype)
     w = jax.random.normal(kw, (k, n), dtype)
-    out = arype_matmul(x, w, activation=act)
+    out = arype_matmul(x, w, activation=act, interpret=True)
     ref = ref_matmul(x, w, activation=act)
     tol = 2e-2 if dtype == jnp.bfloat16 else 2e-4
     np.testing.assert_allclose(np.asarray(out, np.float32), np.asarray(ref, np.float32),
@@ -34,8 +34,8 @@ def test_arype_matmul_sweep(m, k, n, dtype, act):
 def test_arype_unfused_matches_fused():
     x = jax.random.normal(jax.random.PRNGKey(0), (96, 384), jnp.float32)
     w = jax.random.normal(jax.random.PRNGKey(1), (384, 160), jnp.float32)
-    a = arype_matmul(x, w)
-    b = arype_matmul_unfused(x, w)
+    a = arype_matmul(x, w, interpret=True)
+    b = arype_matmul_unfused(x, w, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=1e-5, atol=1e-4)
 
 
@@ -47,7 +47,7 @@ def test_vpe_matmul_sweep(m, k, n, act):
     kx, kw = jax.random.split(jax.random.PRNGKey(m + k * 7 + n))
     x = jax.random.normal(kx, (m, k), jnp.float32)
     w = jax.random.normal(kw, (k, n), jnp.float32)
-    out = vpe_matmul(x, w, activation=act)
+    out = vpe_matmul(x, w, activation=act, interpret=True)
     ref = ref_vpe_matmul(x, w, activation=act)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-5, atol=1e-5)
 
@@ -67,7 +67,7 @@ def test_flash_attention_sweep(b, hq, hkv, sq, sk, d, mask, win, dtype):
     q = jax.random.normal(ks[0], (b, hq, sq, d), dtype)
     k = jax.random.normal(ks[1], (b, hkv, sk, d), dtype)
     v = jax.random.normal(ks[2], (b, hkv, sk, d), dtype)
-    out = flash_attention(q, k, v, mask=mask, window=win)
+    out = flash_attention(q, k, v, mask=mask, window=win, interpret=True)
     g = hq // hkv
     kr = jnp.repeat(k, g, 1).reshape(b * hq, sk, d)
     vr = jnp.repeat(v, g, 1).reshape(b * hq, sk, d)
@@ -87,7 +87,8 @@ def test_flash_attention_property(sq, sk, d, mask):
     q = jax.random.normal(ks[0], (1, 2, sq, d), jnp.float32)
     k = jax.random.normal(ks[1], (1, 2, sk, d), jnp.float32)
     v = jax.random.normal(ks[2], (1, 2, sk, d), jnp.float32)
-    out = flash_attention(q, k, v, mask=mask, window=13, bq=32, bk=32)
+    out = flash_attention(q, k, v, mask=mask, window=13, bq=32, bk=32,
+                          interpret=True)
     ref = ref_attention(q.reshape(2, sq, d), k.reshape(2, sk, d), v.reshape(2, sk, d),
                         mask=mask, window=13)
     np.testing.assert_allclose(np.asarray(out.reshape(2, sq, d)), np.asarray(ref),
@@ -107,7 +108,7 @@ def test_flow_features_sweep(p, f, block, rng):
     slots, meta = _random_packets(rng, p, f)
     init = jnp.zeros((f, 16), jnp.int32).at[:, 4].set(2**30).at[:, 6].set(2**30)
     prog = default_program()
-    out = flow_feature_update(prog, slots, meta, init, block=block)
+    out = flow_feature_update(prog, slots, meta, init, block=block, interpret=True)
     ref = ref_flow_feature_update(prog, slots, meta, init)
     assert bool(jnp.all(out == ref))
 
@@ -125,7 +126,7 @@ def test_alu_program_property(seed, ops):
     slots = jnp.asarray(rng.integers(0, 7, 64), jnp.int32)
     meta = jnp.asarray(rng.integers(-50, 50, (64, META_WIDTH)), jnp.int32)
     init = jnp.asarray(rng.integers(-5, 5, (8, 16)), jnp.int32)
-    out = flow_feature_update(prog, slots, meta, init, block=32)
+    out = flow_feature_update(prog, slots, meta, init, block=32, interpret=True)
     ref = ref_flow_feature_update(prog, slots, meta, init)
     assert bool(jnp.all(out == ref))
 

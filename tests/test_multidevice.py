@@ -80,8 +80,8 @@ def test_pipeline_parallel_matches_sequential():
     from repro.distributed.pipeline import pipeline_forward, split_stages
     from repro.launch.mesh import make_host_mesh
 
-    from repro.launch.mesh import _axis_type_kwargs
-    mesh = jax.make_mesh((4,), ("pod",), **_axis_type_kwargs(1))
+    from repro.launch.mesh import auto_axes
+    mesh = jax.make_mesh((4,), ("pod",), axis_types=auto_axes(1))
     L, D = 8, 16
     key = jax.random.PRNGKey(0)
     ws = jax.random.normal(key, (L, D, D)) * 0.3
@@ -161,8 +161,7 @@ def test_dryrun_machinery_small_mesh():
             compiled = jax.jit(cell.fn, in_shardings=cell.in_shardings,
                                donate_argnums=cell.donate_argnums
                                ).lower(*cell.args).compile()
-    from repro.launch.roofline import cost_dict
-    ca = cost_dict(compiled)
+    ca = compiled.cost_analysis()
     ma = compiled.memory_analysis()
     coll = parse_collectives(compiled.as_text(), 8)
     assert ca["flops"] > 0
@@ -176,12 +175,11 @@ def test_dryrun_machinery_small_mesh():
 def test_compressed_psum_shard_map():
     run_subprocess("""
     import jax, jax.numpy as jnp, numpy as np, functools
-    from jax.experimental.shard_map import shard_map
     from jax.sharding import PartitionSpec as P
     from repro.distributed.compression import compressed_psum_with_feedback
 
-    from repro.launch.mesh import _axis_type_kwargs
-    mesh = jax.make_mesh((8,), ("data",), **_axis_type_kwargs(1))
+    from repro.launch.mesh import auto_axes
+    mesh = jax.make_mesh((8,), ("data",), axis_types=auto_axes(1))
     g = jax.random.normal(jax.random.PRNGKey(0), (8, 64))
     e = jnp.zeros((8, 64))
 
@@ -189,8 +187,8 @@ def test_compressed_psum_shard_map():
         red, e2 = compressed_psum_with_feedback({"g": g[0]}, {"g": e[0]}, "data")
         return red["g"][None], e2["g"][None]
 
-    f = shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
-                  out_specs=(P("data"), P("data")), check_rep=False)
+    f = jax.shard_map(body, mesh=mesh, in_specs=(P("data"), P("data")),
+                      out_specs=(P("data"), P("data")), check_vma=False)
     red, e2 = f(g, e)
     ref = jnp.mean(g, axis=0)
     # every shard holds the same (approximately mean-reduced) gradient

@@ -74,7 +74,8 @@ def fitted_scales():
 def test_arype_q_matches_int32_oracle(shape, activation, per_channel):
     x, w = _operands(*shape)
     sx, sw = _scales_for(x, w, per_channel)
-    got = arype_matmul_q(x, w, scale_x=sx, scale_w=sw, activation=activation)
+    got = arype_matmul_q(x, w, scale_x=sx, scale_w=sw, activation=activation,
+                         interpret=True)
     want = ref_quantized_matmul(x, w, scale_x=sx, scale_w=sw, activation=activation)
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 
@@ -84,7 +85,8 @@ def test_arype_q_matches_int32_oracle(shape, activation, per_channel):
 def test_vpe_q_matches_int32_oracle(shape, per_channel):
     x, w = _operands(*shape, seed=1)
     sx, sw = _scales_for(x, w, per_channel)
-    got = vpe_matmul_q(x, w, scale_x=sx, scale_w=sw, activation="relu")
+    got = vpe_matmul_q(x, w, scale_x=sx, scale_w=sw, activation="relu",
+                       interpret=True)
     want = ref_quantized_matmul(x, w, scale_x=sx, scale_w=sw, activation="relu")
     np.testing.assert_array_equal(np.asarray(got), np.asarray(want))
 

@@ -1,0 +1,101 @@
+"""Ahead-of-time compiles for a described (not attached) TPU v5e chip.
+
+The TPU compiler is installed on CPU hosts too: a program lowered against a
+described topology is compiled exactly as the chip's compiler would compile
+it, so what Mosaic or XLA refuses shows up here, at real sizes, without a
+chip.  Nothing runs, so these tests say nothing about results or times.
+
+The topology is described inside a module fixture — never while a module is
+imported — and every test here skips when it cannot be described.
+"""
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from repro.kernels.arype_matmul import arype_matmul
+from repro.kernels.flow_features.ops import META_WIDTH, fold_features
+from repro.kernels.vpe_smallmm import vpe_matmul
+from repro.models import paper_models
+from repro.runtime import RuntimeConfig
+from repro.serving import OctopusPipeline, PipelineConfig
+
+TABLE = 8192  # the paper's flow table
+BATCH = 256
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    from jax.experimental import topologies
+
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # no TPU compiler in this installation
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # a compile for a described chip cannot be read back from the persistent
+    # cache without that chip; keep the cache out of these compiles
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was)
+
+
+def _sds(sharding, shape, dtype=jnp.int32):
+    return jax.ShapeDtypeStruct(shape, dtype, sharding=sharding)
+
+
+def _abstract(tree, sharding):
+    return jax.tree_util.tree_map(
+        lambda a: _sds(sharding, a.shape, a.dtype), tree)
+
+
+def test_arype_matmul_compiles(one_chip):
+    f32 = jnp.float32
+    compiled = arype_matmul.lower(_sds(one_chip, (1024, 256), f32),
+                                  _sds(one_chip, (256, 128), f32),
+                                  interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_vpe_matmul_compiles_at_cnn_first_layer(one_chip):
+    f32 = jnp.float32
+    compiled = vpe_matmul.lower(_sds(one_chip, (20000, 3), f32),
+                                _sds(one_chip, (3, 32), f32),
+                                interpret=False).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+def test_flow_features_fold_compiles(one_chip):
+    def fold(program, slots, meta, feats, keep):
+        return fold_features(program, slots, meta, feats, keep=keep,
+                             interpret=False)
+
+    compiled = jax.jit(fold).lower(
+        _sds(one_chip, (16, 3)), _sds(one_chip, (BATCH,)),
+        _sds(one_chip, (BATCH, META_WIDTH)), _sds(one_chip, (TABLE, 16)),
+        _sds(one_chip, (BATCH,), jnp.bool_)).compile()
+    assert "tpu_custom_call" in compiled.as_text()
+
+
+@pytest.mark.parametrize("use_pallas,cold_size",
+                         [(False, 0), (True, 0), (False, 131072)])
+def test_pipeline_step_compiles(one_chip, use_pallas, cold_size):
+    """The fused step (tracker merge, drain, both engines, decide) at the
+    paper's table depth, hot-only and with a cold tier.  Plain XLA without
+    Pallas; with it, the tracker fold and both engine kernels are Mosaic
+    custom calls."""
+    cfg = PipelineConfig(batch_size=BATCH, max_ready=64, flow_model="cnn",
+                         table_size=TABLE, cold_size=cold_size)
+    pipe = OctopusPipeline(
+        paper_models.init_paper_model("mlp", jax.random.PRNGKey(0)),
+        paper_models.init_paper_model("cnn", jax.random.PRNGKey(1)), cfg,
+        config=RuntimeConfig(use_pallas=use_pallas, interpret=False))
+    compiled = jax.jit(pipe._step).lower(
+        _abstract(pipe.state, one_chip),
+        _abstract(pipe._zero_batch(), one_chip)).compile()
+    text = compiled.as_text()
+    assert ("tpu_custom_call" in text) == use_pallas
