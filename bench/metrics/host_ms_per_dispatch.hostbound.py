@@ -1,0 +1,4 @@
+"""host_ms_per_dispatch in a cell whose pace the host sets, where it moves
+pkt_per_s.hostbound.  A per-layer metric names the one end-to-end metric
+it moves, so the same reading takes a second name, and this file."""
+from bench.metrics.host_ms_per_dispatch import read  # noqa: F401
