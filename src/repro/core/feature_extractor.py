@@ -327,31 +327,32 @@ def segmented_update(
         seg_spills = None
 
     def with_fallback(_):
-        if with_spills:
-            scan_state, outs, scan_spills = ft.process_packets(
-                state, packets, program, top_n=top_n, keep=keep,
-                with_spills=True)
-        else:
-            scan_state, outs = ft.process_packets(state, packets, program,
-                                                  top_n=top_n, keep=keep)
-            scan_spills = None
+        with jax.named_scope("track.fallback"):  # device-trace name
+            if with_spills:
+                scan_state, outs, scan_spills = ft.process_packets(
+                    state, packets, program, top_n=top_n, keep=keep,
+                    with_spills=True)
+            else:
+                scan_state, outs = ft.process_packets(state, packets, program,
+                                                      top_n=top_n, keep=keep)
+                scan_spills = None
 
-        def pick(seg_leaf, scan_leaf):
-            m = collide.reshape((F,) + (1,) * (seg_leaf.ndim - 1))
-            return jnp.where(m, scan_leaf, seg_leaf)
+            def pick(seg_leaf, scan_leaf):
+                m = collide.reshape((F,) + (1,) * (seg_leaf.ndim - 1))
+                return jnp.where(m, scan_leaf, seg_leaf)
 
-        merged = jax.tree_util.tree_map(pick, seg_state, scan_state)
-        new = new_nc + jnp.sum(outs.new_flow & pkt_collides).astype(jnp.int32)
-        ev = ev_nc + jnp.sum(outs.evicted & pkt_collides).astype(jnp.int32)
-        if not with_spills:
-            return merged, new, ev, None
+            merged = jax.tree_util.tree_map(pick, seg_state, scan_state)
+            new = new_nc + jnp.sum(outs.new_flow & pkt_collides).astype(jnp.int32)
+            ev = ev_nc + jnp.sum(outs.evicted & pkt_collides).astype(jnp.int32)
+            if not with_spills:
+                return merged, new, ev, None
 
-        def pick_pkt(seg_leaf, scan_leaf):
-            m = pkt_collides.reshape((P,) + (1,) * (seg_leaf.ndim - 1))
-            return jnp.where(m, scan_leaf, seg_leaf)
+            def pick_pkt(seg_leaf, scan_leaf):
+                m = pkt_collides.reshape((P,) + (1,) * (seg_leaf.ndim - 1))
+                return jnp.where(m, scan_leaf, seg_leaf)
 
-        return merged, new, ev, jax.tree_util.tree_map(pick_pkt, seg_spills,
-                                                       scan_spills)
+            return merged, new, ev, jax.tree_util.tree_map(pick_pkt, seg_spills,
+                                                           scan_spills)
 
     def without_fallback(_):
         return seg_state, new_nc, ev_nc, seg_spills

@@ -53,6 +53,7 @@ from repro.runtime.routing import (
     route_matmul,
     systolic_utilization,
 )
+from repro.runtime.trace import SpanTotal, span
 
 __all__ = [
     "Calibration",
@@ -65,6 +66,7 @@ __all__ = [
     "RoutePlan",
     "RuntimeConfig",
     "ShapeTiming",
+    "SpanTotal",
     "calibrate",
     "current_runtime",
     "fit_crossover",
@@ -81,5 +83,6 @@ __all__ = [
     "route_matmul",
     "runtime_overrides",
     "save_calibration",
+    "span",
     "systolic_utilization",
 ]

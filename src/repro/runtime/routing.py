@@ -19,6 +19,8 @@ from contextvars import ContextVar
 from dataclasses import dataclass
 from typing import Iterator, List, Optional
 
+import jax
+
 from repro.common.util import ceil_div
 from repro.runtime.config import RuntimeConfig, current_runtime
 
@@ -65,11 +67,14 @@ def name_scope(label: str) -> Iterator[None]:
     """Prefix recorded matmul names with ``label/`` within the block (nesting
     joins with ``/``).  Lets a composite trace — e.g. the streaming pipeline's
     packet + flow engines — keep its sub-models distinguishable inside one
-    :class:`repro.runtime.plan.RoutePlan`."""
+    :class:`repro.runtime.plan.RoutePlan`.  The block is also a
+    ``jax.named_scope``, so operations traced inside it carry the same label
+    in their ``op_name`` (and in a device trace)."""
     outer = _name_scope.get()
     token = _name_scope.set(f"{outer}{label}/")
     try:
-        yield
+        with jax.named_scope(label):
+            yield
     finally:
         _name_scope.reset(token)
 

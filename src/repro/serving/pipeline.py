@@ -52,11 +52,18 @@ tested).  :class:`PipelineStats` splits ``host_us`` vs ``device_us`` per
 dispatch so the overlap is measured, not claimed: ``device_us`` is the
 *exposed* device wait (what the host actually blocked on), which shrinks as
 staging hides under execution.
+
+Every host interval is a :class:`repro.runtime.span` (``octopus.pull``,
+``.enqueue``, ``.wait``, ``.readback``, ``.feedback``, ``.counters``; the
+served ``step_masked`` wraps them in ``octopus.step``), totalled per name in
+``PipelineStats.spans`` and written to the profiler's trace when it runs.
+The step's device work runs under ``jax.named_scope`` names: ``track.promote``,
+``track.merge`` (``track.fallback`` inside it), ``track.spill``,
+``track.scrub``, ``drain``, ``engine.pkt`` and ``engine.flow``.
 """
 from __future__ import annotations
 
 import itertools
-import time
 from dataclasses import dataclass, field
 from typing import Any, Iterable, NamedTuple, Optional, Sequence
 
@@ -72,7 +79,7 @@ from repro.core import flow_tracker as ft
 from repro.core.feature_extractor import packet_meta_features
 from repro.kernels.flow_features.ops import default_program
 from repro.models import paper_models
-from repro.runtime import RoutePlan, RuntimeConfig, name_scope, resolve_config
+from repro.runtime import RoutePlan, RuntimeConfig, name_scope, resolve_config, span
 from repro.serving.packet_path import FLOW_MODELS, FlowEngine, PacketEngine
 
 TRACKERS = ("segmented", "scan")
@@ -162,6 +169,22 @@ class PipelineStepOutput(NamedTuple):
     evicted: jax.Array  # () int32 — stale flows recycled by collision
     spilled: jax.Array  # () int32 — evictions spilled into the cold store
     promoted: jax.Array  # () int32 — cold entries promoted back into hot
+    fallback_slots: jax.Array  # () int32 — table slots whose in-batch
+    # collision took the segmented tracker's scan fallback
+    ready_left: jax.Array  # () int32 — ready flows left undrained (max_ready)
+
+
+# the step counters, read back together in one transfer per dispatch
+COUNTERS = ("new_flows", "evicted", "spilled", "promoted", "fallback_slots",
+            "ready_left")
+
+
+def _host_outputs(out: PipelineStepOutput) -> tuple[np.ndarray, ...]:
+    """What step 6 reads of one dispatch, on the host: packet actions,
+    drained mask and tuple ids, flow actions and classes."""
+    return (np.asarray(out.pkt_actions), np.asarray(out.drained.mask),
+            np.asarray(out.drained.tuple_id), np.asarray(out.flow_actions),
+            np.asarray(out.flow_cls))
 
 
 class LatencyReservoir:
@@ -224,7 +247,8 @@ class PipelineStats:
     Beyond the aggregate means (``dispatch_us``/``step_us``), every timed
     dispatch region also lands one sample in a bounded
     :class:`LatencyReservoir`, so tail latency (``p50_us``/``p99_us``) is
-    reportable over unbounded runs — idle stats report ``nan``."""
+    reportable over unbounded runs — idle stats report ``nan``.  ``spans``
+    totals every host span by name (:class:`repro.runtime.SpanTotal`)."""
 
     steps: int = 0
     total_s: float = 0.0
@@ -237,15 +261,21 @@ class PipelineStats:
     dispatches: int = 0  # host->device round-trips (chunking lowers it below
     # steps; sharded overflow rounds raise it above)
     padded: int = 0  # dispatched-but-masked lane rows (sharding skew cost)
-    host_s: float = 0.0  # host-side share: staging, enqueue, feedback, pulls
-    device_s: float = 0.0  # EXPOSED device wait — what the host blocked on,
-    # not raw execution time; overlap shrinks it by hiding staging under it
+    fallback_dispatches: int = 0  # dispatches whose merge took the fallback
+    fallback_slots: int = 0  # table slots that took it, summed
+    ready_left: int = 0  # ready flows left undrained after each drain, summed
+    host_s: float = 0.0  # host-side share: pull, enqueue (and partition),
+    # readback and feedback spans — not the counters' read
+    device_s: float = 0.0  # EXPOSED device wait (the ``octopus.wait`` span) —
+    # what the host blocked on, not raw execution time; overlap shrinks it
     lat: LatencyReservoir = field(default_factory=LatencyReservoir)
+    spans: dict = field(default_factory=dict)  # name -> SpanTotal
 
     def record_dispatch(self, dt: float, *, packets: int, steps: int = 1,
                         dispatches: int = 1, flows: int = 0,
                         new_flows: int = 0, evicted: int = 0,
                         spilled: int = 0, promoted: int = 0,
+                        fallback_slots: int = 0, ready_left: int = 0,
                         padded: int = 0, host_s: float = 0.0,
                         device_s: float = 0.0) -> None:
         """Fold one timed dispatch (or fused multi-step chunk) into the
@@ -263,6 +293,9 @@ class PipelineStats:
         self.evicted += evicted
         self.spilled += spilled
         self.promoted += promoted
+        self.fallback_dispatches += fallback_slots > 0
+        self.fallback_slots += fallback_slots
+        self.ready_left += ready_left
         self.padded += padded
         self.host_s += host_s
         self.device_s += device_s
@@ -289,8 +322,8 @@ class PipelineStats:
 
     @property
     def host_us(self) -> float:
-        """Mean host-side time per dispatch: staging + enqueue + rule-table
-        feedback (+ the producer pull when driven by ``run``)."""
+        """Mean host-side time per dispatch: staging + enqueue + read-back +
+        rule-table feedback (+ the producer pull when driven by ``run``)."""
         return self.host_s / self.dispatches * 1e6 if self.dispatches else float("nan")
 
     @property
@@ -414,34 +447,30 @@ class OctopusPipeline:
                keep: Optional[jax.Array], *, fallback: str,
                with_spills: bool = False):
         """The raw tracker merge under ``cfg.tracker``: returns
-        ``(hot, new, evicted)`` (plus the spill records when asked)."""
+        ``(hot, new, evicted, fallback_slots)`` (plus the spill records when
+        asked).  The scan tracker is the serial oracle: it never falls back."""
         if self.cfg.tracker == "segmented":
             out = fx.segmented_update(
                 hot, packets, self.program, top_n=self.cfg.top_n,
                 use_pallas=self.runtime.use_pallas,
                 interpret=self.runtime.interpret, keep=keep,
                 fallback=fallback, with_spills=with_spills)
-            if with_spills:
-                hot, seg, spills = out
-                return hot, seg.new_flows, seg.evicted, spills
-            hot, seg = out
-            return hot, seg.new_flows, seg.evicted
+            hot, seg = out[:2]
+            return (hot, seg.new_flows, seg.evicted, seg.fallback_slots,
+                    *out[2:])
         out = ft.process_packets(hot, packets, self.program,
                                  top_n=self.cfg.top_n, keep=keep,
                                  with_spills=with_spills)
-        if with_spills:
-            hot, outs, spills = out
-            return (hot, outs.new_flow.sum().astype(jnp.int32),
-                    outs.evicted.sum().astype(jnp.int32), spills)
-        hot, outs = out
+        hot, outs = out[:2]
         return (hot, outs.new_flow.sum().astype(jnp.int32),
-                outs.evicted.sum().astype(jnp.int32))
+                outs.evicted.sum().astype(jnp.int32), jnp.int32(0), *out[2:])
 
     def _track(self, state, packets: ft.PacketBatch,
                keep: Optional[jax.Array] = None, *, fallback: str = "auto"):
         """Step 2 only: merge one (optionally masked) microbatch into the
         tracker under ``cfg.tracker``.  Returns ``(state, new_flows,
-        evicted, spilled, promoted)`` — the merge half of the lane contract,
+        evicted, spilled, promoted, fallback_slots)`` — the merge half of the
+        lane contract,
         dispatched on its own by the sharded pipeline's overflow rounds.
         ``fallback`` is forwarded to the segmented tracker's collision
         branch (vmapped callers hoist it).
@@ -453,20 +482,25 @@ class OctopusPipeline:
         same merge: promote -> merge (with spill records) -> spill -> scrub."""
         zero = jnp.int32(0)
         if not self.cfg.cold_size:
-            state, new, ev = self._merge(state, packets, keep,
-                                         fallback=fallback)
-            return state, new, ev, zero, zero
+            with jax.named_scope("track.merge"):
+                state, new, ev, fb = self._merge(state, packets, keep,
+                                                 fallback=fallback)
+            return state, new, ev, zero, zero, fb
         hot, cold = state.hot, state.cold
-        hot, cold, promoted = cold_store.promote_pass(
-            hot, cold, packets, keep, policy=self.cfg.cold_policy)
-        hot, new, ev, spills = self._merge(hot, packets, keep,
-                                           fallback=fallback,
-                                           with_spills=True)
-        cold, spilled = cold_store.apply_spills(
-            cold, spills, policy=self.cfg.cold_policy)
-        cold = cold_store.scrub_live(cold, hot, packets, keep)
+        with jax.named_scope("track.promote"):
+            hot, cold, promoted = cold_store.promote_pass(
+                hot, cold, packets, keep, policy=self.cfg.cold_policy)
+        with jax.named_scope("track.merge"):
+            hot, new, ev, fb, spills = self._merge(hot, packets, keep,
+                                                   fallback=fallback,
+                                                   with_spills=True)
+        with jax.named_scope("track.spill"):
+            cold, spilled = cold_store.apply_spills(
+                cold, spills, policy=self.cfg.cold_policy)
+        with jax.named_scope("track.scrub"):
+            cold = cold_store.scrub_live(cold, hot, packets, keep)
         return (cold_store.TwoLevelState(hot, cold), new, ev, spilled,
-                promoted)
+                promoted, fb)
 
     def _lane_core(self, state, packets: ft.PacketBatch,
                    keep: Optional[jax.Array] = None, *,
@@ -480,12 +514,15 @@ class OctopusPipeline:
         hash-partitioned lanes.  Draining always happens on the hot bank —
         cold flows re-enter the hot table through promotion before they can
         emit."""
-        state, new_flows, evicted, spilled, promoted = self._track(
-            state, packets, keep, fallback=fallback)
+        state, new_flows, evicted, spilled, promoted, fallback_slots = \
+            self._track(state, packets, keep, fallback=fallback)
         hot = state.hot if self.cfg.cold_size else state
-        hot, drained = ft.drain_ready(
-            hot, top_n=self.cfg.top_n,
-            max_ready=self.cfg.max_ready if max_ready is None else max_ready)
+        with jax.named_scope("drain"):
+            ready = ft.ready_mask(hot, top_n=self.cfg.top_n).sum()
+            hot, drained = ft.drain_ready(
+                hot, top_n=self.cfg.top_n,
+                max_ready=self.cfg.max_ready if max_ready is None else max_ready)
+            ready_left = (ready - drained.mask.sum()).astype(jnp.int32)
         state = state._replace(hot=hot) if self.cfg.cold_size else hot
         pkt_actions = self._decide_pkt(packets)
         flow_actions, flow_cls, flow_scores = self._decide_flow(drained)
@@ -499,6 +536,8 @@ class OctopusPipeline:
             evicted=evicted,
             spilled=spilled,
             promoted=promoted,
+            fallback_slots=fallback_slots,
+            ready_left=ready_left,
         )
 
     # ------------------------------------------------------------ decide (5)
@@ -507,9 +546,11 @@ class OctopusPipeline:
         consumes logits (feature-only heads skip the inference entirely),
         then let the head decide."""
         head = self.cfg.pkt_head
-        logits = self.packet_engine.fn(
-            self.packet_engine.params,
-            packet_meta_features(packets)) if head.needs_logits else None
+        logits = None
+        if head.needs_logits:
+            with jax.named_scope("engine.pkt"):
+                logits = self.packet_engine.fn(self.packet_engine.params,
+                                               packet_meta_features(packets))
         return head.decide(logits, packets)
 
     def _decide_flow(self, drained: ft.DrainResult
@@ -518,11 +559,11 @@ class OctopusPipeline:
         logits-consuming heads, then the head maps (logits, drained rows) to
         (actions, classes, scores)."""
         head = self.cfg.flow_head
+        logits = None
         if head.needs_logits:
-            flow_x = self.flow_engine.prep(drained.series, drained.payload)
-            logits = self.flow_engine.fn(self.flow_engine.params, flow_x)
-        else:
-            logits = None
+            with jax.named_scope("engine.flow"):
+                flow_x = self.flow_engine.prep(drained.series, drained.payload)
+                logits = self.flow_engine.fn(self.flow_engine.params, flow_x)
         return head.decide(logits, drained)
 
     def _decide(self, packets: ft.PacketBatch, drained: ft.DrainResult
@@ -618,37 +659,69 @@ class OctopusPipeline:
                               flow_cls[mask])
         return n_flows
 
+    def _complete(self, out: PipelineStepOutput, readback, *, host_s: float,
+                  rounds: Sequence[dict] = (), **record) -> dict:
+        """The host's half of one enqueued dispatch, after the enqueue: wait
+        for ``out``, read back what step 6 needs (``readback()`` returns one
+        :meth:`_feedback` argument tuple per step), feed the rule table, read
+        every counter back in one transfer and record the dispatch.
+        ``host_s`` is the host time already spent on it (pull, partition,
+        enqueue); ``rounds`` holds the counters of earlier merge-only rounds
+        (sharded overflow).  The counters' read is timed apart from
+        ``host_s``.  Returns the counters, summed over steps and rounds."""
+        st = self.stats
+        with span("octopus.wait", st) as wait:
+            jax.block_until_ready(out)
+        with span("octopus.readback", st) as rb:
+            steps = readback()
+        with span("octopus.feedback", st) as fb:
+            flows = sum(self._feedback(*args) for args in steps)
+        with span("octopus.counters", st):
+            got = jax.device_get([{k: getattr(out, k) for k in COUNTERS},
+                                  *rounds])
+        counters = {k: int(sum(np.sum(c[k]) for c in got if k in c))
+                    for k in COUNTERS}
+        host_s += rb.s + fb.s
+        st.record_dispatch(host_s + wait.s, flows=flows, host_s=host_s,
+                           device_s=wait.s, **counters, **record)
+        return counters
+
+    def _chunk_rows(self, batches: Sequence[ft.PacketBatch],
+                    out: PipelineStepOutput) -> list[tuple]:
+        """Step 6's arguments for one fused chunk (stacked outputs, leading
+        step axis), one tuple per step in step order so later verdicts
+        overwrite earlier — shared by the single-lane and sharded chunked
+        dispatches.  The hashes come from the host-resident ``batches``;
+        reading them back from the stacked device arrays would add a
+        device->host transfer per chunk."""
+        hashes = np.stack([np.asarray(b.tuple_hash) for b in batches])
+        return list(zip(hashes, *_host_outputs(out)))
+
+    def _masked_rows(self, packets: ft.PacketBatch, keep: np.ndarray,
+                     out: PipelineStepOutput) -> list[tuple]:
+        """Step 6's arguments for one padded bucket: kept rows only."""
+        pkt_actions, *flows = _host_outputs(out)
+        return [(np.asarray(packets.tuple_hash)[keep], pkt_actions[keep],
+                 *flows)]
+
     def _dispatch_step(self, packets: ft.PacketBatch) -> InflightDispatch:
         """Enqueue one microbatch (steps 2-5) without blocking — JAX async
         dispatch hands the outputs back as future arrays, so the host is
         free to stage the next chunk while this one executes.  The returned
         handle's ``wait`` blocks, applies feedback and records stats."""
         n = self._check_batch(packets)
-        t0 = time.perf_counter()
-        self.state, out = self._step_fn(self.state, packets)
-        enqueue_s = time.perf_counter() - t0
+        with span("octopus.enqueue", self.stats) as enq:
+            self.state, out = self._step_fn(self.state, packets)
         self._step_warmed = True  # compiled now, whatever the entry path
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
             # block on the outputs only: under overlap the state has already
             # been donated to the next enqueued dispatch (same computation,
             # so `out` ready implies the state update finished too)
-            t1 = time.perf_counter()
-            jax.block_until_ready(out)
-            device_s = time.perf_counter() - t1
-            t2 = time.perf_counter()
-            n_flows = self._feedback(
-                np.asarray(packets.tuple_hash), np.asarray(out.pkt_actions),
-                np.asarray(out.drained.mask),
-                np.asarray(out.drained.tuple_id),
-                np.asarray(out.flow_actions), np.asarray(out.flow_cls))
-            host_s = (enqueue_s + host_extra_s
-                      + (time.perf_counter() - t2))
-            self.stats.record_dispatch(
-                host_s + device_s, packets=n, flows=n_flows,
-                new_flows=int(out.new_flows), evicted=int(out.evicted),
-                spilled=int(out.spilled), promoted=int(out.promoted),
-                host_s=host_s, device_s=device_s)
+            self._complete(
+                out, lambda: [(np.asarray(packets.tuple_hash),
+                               *_host_outputs(out))],
+                host_s=enq.s + host_extra_s, packets=n)
             return out
 
         return InflightDispatch(finish, steps=1, packets=n)
@@ -687,57 +760,23 @@ class OctopusPipeline:
         False`` are padding — excluded from the tracker merge, the rule-table
         feedback and the packet stats (they count as ``padded``, like a
         sharded lane's skew rows).  The batch may be any pre-warmed bucket
-        size; it is NOT tied to ``cfg.batch_size``."""
+        size; it is NOT tied to ``cfg.batch_size``.  The whole call is the
+        ``octopus.step`` span, tagged with this dispatch's number (the
+        ``dispatches`` count before it) and its bucket."""
         bucket = int(np.asarray(packets.ts).shape[0])
         k = np.asarray(keep)
         if k.shape != (bucket,):
             raise ValueError(f"keep must have shape ({bucket},), got {k.shape}")
         n = int(k.sum())
-        t0 = time.perf_counter()
-        self.state, out = self._masked_fn(self.state, packets,
-                                          jnp.asarray(k))
-        t1 = time.perf_counter()
-        jax.block_until_ready((self.state, out))
-        t2 = time.perf_counter()
-        self._warm_buckets.add(bucket)  # compiled now, whatever the path
-
-        n_flows = self._feedback(
-            np.asarray(packets.tuple_hash)[k], np.asarray(out.pkt_actions)[k],
-            np.asarray(out.drained.mask), np.asarray(out.drained.tuple_id),
-            np.asarray(out.flow_actions), np.asarray(out.flow_cls))
-        t3 = time.perf_counter()
-
-        host_s, device_s = (t1 - t0) + (t3 - t2), t2 - t1
-        self.stats.record_dispatch(host_s + device_s, packets=n,
-                                   flows=n_flows,
-                                   new_flows=int(out.new_flows),
-                                   evicted=int(out.evicted),
-                                   spilled=int(out.spilled),
-                                   promoted=int(out.promoted),
-                                   padded=bucket - n,
-                                   host_s=host_s, device_s=device_s)
+        st = self.stats
+        with span("octopus.step", st, dispatch=st.dispatches, bucket=bucket):
+            with span("octopus.enqueue", st) as enq:
+                self.state, out = self._masked_fn(self.state, packets,
+                                                  jnp.asarray(k))
+            self._warm_buckets.add(bucket)  # compiled now, whatever the path
+            self._complete(out, lambda: self._masked_rows(packets, k, out),
+                           host_s=enq.s, packets=n, padded=bucket - n)
         return out
-
-    def _chunk_feedback(self, batches: Sequence[ft.PacketBatch],
-                        out: PipelineStepOutput) -> int:
-        """Step 6 for one fused chunk (stacked outputs, leading step axis),
-        applied in step order so later verdicts overwrite earlier — shared by
-        the single-lane and sharded chunked dispatches.  Returns the number
-        of emitted flows.  The hashes come from the host-resident ``batches``;
-        reading them back from the stacked device arrays would add a
-        device->host transfer per chunk."""
-        hashes = np.stack([np.asarray(b.tuple_hash) for b in batches])
-        pkt_actions = np.asarray(out.pkt_actions)
-        masks = np.asarray(out.drained.mask)
-        tuple_ids = np.asarray(out.drained.tuple_id)
-        flow_actions = np.asarray(out.flow_actions)
-        flow_cls = np.asarray(out.flow_cls)
-        n_flows = 0
-        for j in range(len(batches)):
-            n_flows += self._feedback(hashes[j], pkt_actions[j], masks[j],
-                                      tuple_ids[j], flow_actions[j],
-                                      flow_cls[j])
-        return n_flows
 
     def _dispatch_chunk(self, batches: Sequence[ft.PacketBatch]
                         ) -> InflightDispatch:
@@ -752,27 +791,15 @@ class OctopusPipeline:
                              f"microbatches, got {len(batches)}")
         for b in batches:
             self._check_batch(b)
-        t0 = time.perf_counter()
-        stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *batches)
-        self.state, out = self._chunk_fn(self.state, stacked)
-        enqueue_s = time.perf_counter() - t0
+        with span("octopus.enqueue", self.stats) as enq:
+            stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
+                                             *batches)
+            self.state, out = self._chunk_fn(self.state, stacked)
         n = L * self.cfg.batch_size
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
-            t1 = time.perf_counter()
-            jax.block_until_ready(out)
-            device_s = time.perf_counter() - t1
-            t2 = time.perf_counter()
-            n_flows = self._chunk_feedback(batches, out)
-            host_s = (enqueue_s + host_extra_s
-                      + (time.perf_counter() - t2))
-            self.stats.record_dispatch(
-                host_s + device_s, packets=n, steps=L, flows=n_flows,
-                new_flows=int(np.asarray(out.new_flows).sum()),
-                evicted=int(np.asarray(out.evicted).sum()),
-                spilled=int(np.asarray(out.spilled).sum()),
-                promoted=int(np.asarray(out.promoted).sum()),
-                host_s=host_s, device_s=device_s)
+            self._complete(out, lambda: self._chunk_rows(batches, out),
+                           host_s=enq.s + host_extra_s, packets=n, steps=L)
             return out
 
         return InflightDispatch(finish, steps=L, packets=n)
@@ -824,9 +851,9 @@ class OctopusPipeline:
             want = L if steps is None else min(L, steps - done)
             # islice, not enumerate+break: never pull a batch beyond `steps`
             # (a generator reused across run() calls must not drop batches)
-            t0 = time.perf_counter()
-            chunk = list(itertools.islice(it, want))
-            pull_s = time.perf_counter() - t0
+            with span("octopus.pull", self.stats) as pull:
+                chunk = list(itertools.islice(it, want))
+            pull_s = pull.s
             if not chunk:
                 break
             if L > 1 and len(chunk) == L:

@@ -59,6 +59,7 @@ import numpy as np
 
 from repro.core.flow_tracker import PacketBatch
 from repro.data.traffic import TrafficGenerator
+from repro.runtime import span
 from repro.serving.pipeline import LatencyReservoir, OctopusPipeline
 
 ADMISSION_POLICIES = ("shed", "block")
@@ -114,6 +115,8 @@ class ServeResult:
     e2e_s: float  # enqueue -> verdicts ready
     buckets: tuple[int, ...] = ()  # per-chunk dispatch buckets, in order
     # (an oversize submit splits into several chunks; each records its own)
+    dispatches: tuple[int, ...] = ()  # per-chunk pipeline dispatch numbers
+    # (the ``dispatch`` argument of that step's ``octopus.step`` span)
 
 
 @dataclass(frozen=True)
@@ -163,8 +166,11 @@ class ServiceStats:
     pool_misses: int = 0
     failed_dispatches: int = 0  # dispatches whose step raised
     failed: int = 0  # packets answered with an error instead of verdicts
-    host_s: float = 0.0  # dispatch host share: staging-buffer pack + slicing
-    device_s: float = 0.0  # dispatch device share: the masked-step block
+    host_s: float = 0.0  # dispatch host share: staging-buffer pack, copies
+    # to and from the device (the ``octopus.pack``, ``.h2d`` and ``.d2h`` spans)
+    device_s: float = 0.0  # time blocked on the device: the pipeline's
+    # ``octopus.wait`` over this service's dispatches
+    spans: dict = field(default_factory=dict)  # name -> SpanTotal
     started_at: float = 0.0  # perf_counter anchor set by start(); 0 = never
     stopped_at: float = 0.0  # freeze anchor set by stop(); 0 while running
     wait: LatencyReservoir = field(default_factory=LatencyReservoir)
@@ -199,12 +205,14 @@ class ServiceStats:
 
     @property
     def host_us(self) -> float:
-        """Mean host share per dispatch (pack + result slicing)."""
+        """Mean host share per dispatch (staging pack + device copies)."""
         return self.host_s / self.dispatches * 1e6 if self.dispatches else float("nan")
 
     @property
     def device_us(self) -> float:
-        """Mean device share per dispatch (the masked-step block)."""
+        """Mean time per dispatch blocked on the device (the pipeline's
+        ``octopus.wait``; its enqueue, read-back and feedback are the
+        pipeline's ``host_us``)."""
         return self.device_s / self.dispatches * 1e6 if self.dispatches else float("nan")
 
 
@@ -250,6 +258,7 @@ class _Pending:
     future: asyncio.Future
     dispatched_at: float = 0.0
     bucket: int = 0  # the bucket this chunk actually dispatched in
+    dispatch: int = -1  # the pipeline dispatch number it rode in
 
 
 class OctopusService:
@@ -417,7 +426,7 @@ class OctopusService:
             st.e2e.add(e2e_s * 1e6)
         buckets = tuple(c.bucket for c in chunks)
         return ServeResult(client_id, actions, max(buckets), wait_s, e2e_s,
-                           buckets)
+                           buckets, tuple(c.dispatch for c in chunks))
 
     # ------------------------------------------------------------- dispatcher
     def _bucket_for(self, n: int) -> int:
@@ -450,36 +459,41 @@ class OctopusService:
         Returns ``(actions, buf, bucket, host_s, device_s)``."""
         total = sum(r.n for r in reqs)
         bucket = self._bucket_for(total)
-        t0 = time.perf_counter()
-        buf = self._pool.acquire(bucket)
+        st, pipe = self.stats, self.pipeline
+        buf = None
         try:
-            off = 0
-            for r in reqs:
-                for f in _SCALAR_FIELDS:
-                    buf[f][off:off + r.n] = r.leaves[f]
-                buf["payload"][off:off + r.n] = r.leaves["payload"]
-                off += r.n
-            for f in _SCALAR_FIELDS:  # zero the pad tail: stale rows out
-                buf[f][total:] = 0
-            buf["payload"][total:] = 0
-            buf["keep"][:total] = True
-            buf["keep"][total:] = False
+            with span("octopus.pack", st) as pack:
+                buf = self._pool.acquire(bucket)
+                off = 0
+                for r in reqs:
+                    for f in _SCALAR_FIELDS:
+                        buf[f][off:off + r.n] = r.leaves[f]
+                    buf["payload"][off:off + r.n] = r.leaves["payload"]
+                    off += r.n
+                for f in _SCALAR_FIELDS:  # zero the pad tail: stale rows out
+                    buf[f][total:] = 0
+                buf["payload"][total:] = 0
+                buf["keep"][:total] = True
+                buf["keep"][total:] = False
 
-            t_dispatch = time.perf_counter()
-            for r in reqs:
-                r.dispatched_at = t_dispatch
-                r.bucket = bucket
-            batch = PacketBatch(
-                **{f: jnp.asarray(buf[f]) for f in _SCALAR_FIELDS},
-                payload=jnp.asarray(buf["payload"]))
-            t1 = time.perf_counter()
-            out = self.pipeline.step_masked(batch, buf["keep"])
-            t2 = time.perf_counter()
-            actions = np.asarray(out.pkt_actions)
-            host_s = (t1 - t0) + (time.perf_counter() - t2)
-            return actions, buf, bucket, host_s, t2 - t1
+                t_dispatch = time.perf_counter()
+                for r in reqs:
+                    r.dispatched_at = t_dispatch
+                    r.bucket = bucket
+                    r.dispatch = pipe.stats.dispatches  # step_masked's number
+            with span("octopus.h2d", st) as h2d:
+                batch = PacketBatch(
+                    **{f: jnp.asarray(buf[f]) for f in _SCALAR_FIELDS},
+                    payload=jnp.asarray(buf["payload"]))
+            waited = pipe.stats.device_s
+            out = pipe.step_masked(batch, buf["keep"])
+            with span("octopus.d2h", st) as d2h:
+                actions = np.asarray(out.pkt_actions)
+            return (actions, buf, bucket, pack.s + h2d.s + d2h.s,
+                    pipe.stats.device_s - waited)
         except BaseException:
-            self._pool.release(buf)
+            if buf is not None:
+                self._pool.release(buf)
             raise
 
     async def _dispatch_one(self, reqs: list[_Pending]) -> None:
@@ -490,13 +504,16 @@ class OctopusService:
         control never wedges on a failing dispatch."""
         total = sum(r.n for r in reqs)
         try:
-            if self._executor is not None:
-                actions, buf, bucket, host_s, device_s = \
-                    await asyncio.get_running_loop().run_in_executor(
-                        self._executor, self._dispatch_blocking, reqs)
-            else:
-                actions, buf, bucket, host_s, device_s = \
-                    self._dispatch_blocking(reqs)
+            # the loop's wait on the blocking half, the hand-offs to and from
+            # the executor thread included
+            with span("octopus.dispatch", self.stats):
+                if self._executor is not None:
+                    actions, buf, bucket, host_s, device_s = \
+                        await asyncio.get_running_loop().run_in_executor(
+                            self._executor, self._dispatch_blocking, reqs)
+                else:
+                    actions, buf, bucket, host_s, device_s = \
+                        self._dispatch_blocking(reqs)
         except Exception as e:
             self.stats.failed_dispatches += 1
             self.stats.failed += total
@@ -504,10 +521,11 @@ class OctopusService:
                 if not r.future.done():
                     r.future.set_exception(e)
         else:
-            off = 0
-            for r in reqs:
-                r.future.set_result(actions[off:off + r.n].copy())
-                off += r.n
+            with span("octopus.answer", self.stats):
+                off = 0
+                for r in reqs:
+                    r.future.set_result(actions[off:off + r.n].copy())
+                    off += r.n
             self._pool.release(buf)
             self.stats.dispatches += 1
             self.stats.coalesced += len(reqs)
@@ -518,12 +536,14 @@ class OctopusService:
             self._depth -= total
             self._space.set()
 
-    async def _dispatch_loop(self) -> None:
+    async def _next_batch(self) -> Optional[list[_Pending]]:
+        """Wait for queued requests and take a coalesced run of them; None
+        once the service is stopping with nothing left."""
         while True:
             await self._work.wait()
             if not self._queue:
                 if self._stopping:
-                    return
+                    return None
                 self._work.clear()
                 continue
             if self.cfg.batch_wait_s > 0:
@@ -533,9 +553,18 @@ class OctopusService:
             else:
                 # yield once so a gather of submits enqueues as one wave
                 await asyncio.sleep(0)
-            if not self._queue:
-                continue
-            await self._dispatch_one(self._take_coalesced())
+            if self._queue:
+                return self._take_coalesced()
+
+    async def _dispatch_loop(self) -> None:
+        while True:
+            # the batcher's time between dispatches: the clients it yields
+            # to run their submits inside this span
+            with span("octopus.batch", self.stats):
+                reqs = await self._next_batch()
+            if reqs is None:
+                return
+            await self._dispatch_one(reqs)
 
 
 async def serve_stream(service: OctopusService, gen: TrafficGenerator, *,

@@ -47,7 +47,6 @@ result stays bit-exact and the drain still happens once per global batch).
 """
 from __future__ import annotations
 
-import time
 from typing import Any, Optional, Sequence
 
 import jax
@@ -60,12 +59,15 @@ from repro.core import flow_tracker as ft
 from repro.data.traffic import ShardedBatch, partition_batch, shard_of
 from repro.distributed import sharding as shd
 from repro.launch.mesh import make_lanes_mesh
-from repro.runtime import RoutePlan, RuntimeConfig, lane_scope, name_scope, platform
+from repro.runtime import (RoutePlan, RuntimeConfig, lane_scope, name_scope,
+                           platform, span)
 from repro.serving.pipeline import (
+    COUNTERS,
     InflightDispatch,
     OctopusPipeline,
     PipelineConfig,
     PipelineStepOutput,
+    _host_outputs,
 )
 
 LANE_BACKENDS = ("vmap", "shard_map")
@@ -175,10 +177,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
             flow_actions=flat(outs.flow_actions),
             flow_cls=flat(outs.flow_cls),
             flow_scores=flat(outs.flow_scores),
-            new_flows=outs.new_flows.sum().astype(jnp.int32),
-            evicted=outs.evicted.sum().astype(jnp.int32),
-            spilled=outs.spilled.sum().astype(jnp.int32),
-            promoted=outs.promoted.sum().astype(jnp.int32),
+            **{k: getattr(outs, k).sum().astype(jnp.int32) for k in COUNTERS},
         )
 
     # ------------------------------------------------------------ traced cores
@@ -243,8 +242,8 @@ class ShardedOctopusPipeline(OctopusPipeline):
 
         def make_lane(fb):
             def lane(st, p, k):
-                st, new, ev, sp, pr = self._track(st, p, k, fallback=fb)
-                return st, new, ev, sp, pr, self._decide_pkt(p)
+                st, *counts = self._track(st, p, k, fallback=fb)
+                return st, dict(zip(COUNTERS, counts)), self._decide_pkt(p)
 
             return lane
 
@@ -271,61 +270,45 @@ class ShardedOctopusPipeline(OctopusPipeline):
         handle's ``wait`` blocks once, overlays the multi-round packet
         verdicts, applies feedback and records stats."""
         n = self._check_batch(packets)
-        t0 = time.perf_counter()
-        rounds = self._partition(packets)
-        merge_outs = []
-        for sb in rounds[:-1]:
-            (self.state, new, ev, sp, pr,
-             acts) = self._merge_fn(self.state, sb.shards, sb.keep)
-            merge_outs.append((sb, new, ev, sp, pr, acts))
-        last = rounds[-1]
-        self.state, out = self._step_fn(self.state, last.shards, last.keep,
-                                        last.src)
-        enqueue_s = time.perf_counter() - t0
+        st = self.stats
+        with span("octopus.partition", st) as part:
+            rounds = self._partition(packets)
+        with span("octopus.enqueue", st) as enq:
+            merge_outs = []
+            for sb in rounds[:-1]:
+                self.state, counts, acts = self._merge_fn(self.state,
+                                                          sb.shards, sb.keep)
+                merge_outs.append((sb, counts, acts))
+            last = rounds[-1]
+            self.state, out = self._step_fn(self.state, last.shards,
+                                            last.keep, last.src)
         self._step_warmed = True
+        pkt_actions = []  # the verdicts in batch order, once read back
+
+        def readback() -> list[tuple]:
+            acts, *flows = _host_outputs(out)
+            if merge_outs:  # overlay earlier rounds' packet verdicts
+                with span("octopus.scatter", st):
+                    merged = np.zeros((n,), np.int32)
+                    for sb, _, a in merge_outs:
+                        k = np.asarray(sb.keep)
+                        merged[np.asarray(sb.src)[k]] = np.asarray(a)[k]
+                    pos = np.asarray(last.src)[np.asarray(last.keep)]
+                    merged[pos] = acts[pos]
+                    acts = merged
+            pkt_actions.append(acts)
+            return [(np.asarray(packets.tuple_hash), acts, *flows)]
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
-            t1 = time.perf_counter()
-            jax.block_until_ready(out)
-            device_s = time.perf_counter() - t1
-            t2 = time.perf_counter()
-            merged = out
-            if merge_outs:  # overlay earlier rounds' packet verdicts
-                pkt_merged = np.zeros((n,), np.int32)
-                total_new = total_ev = total_sp = total_pr = 0
-                for sb, new, ev, sp, pr, acts in merge_outs:
-                    total_new += int(np.asarray(new).sum())
-                    total_ev += int(np.asarray(ev).sum())
-                    total_sp += int(np.asarray(sp).sum())
-                    total_pr += int(np.asarray(pr).sum())
-                    k = np.asarray(sb.keep)
-                    pkt_merged[np.asarray(sb.src)[k]] = np.asarray(acts)[k]
-                pos = np.asarray(last.src)[np.asarray(last.keep)]
-                pkt_merged[pos] = np.asarray(out.pkt_actions)[pos]
-                merged = out._replace(
-                    pkt_actions=jnp.asarray(pkt_merged),
-                    new_flows=jnp.int32(total_new + int(out.new_flows)),
-                    evicted=jnp.int32(total_ev + int(out.evicted)),
-                    spilled=jnp.int32(total_sp + int(out.spilled)),
-                    promoted=jnp.int32(total_pr + int(out.promoted)))
-
-            n_flows = self._feedback(
-                np.asarray(packets.tuple_hash),
-                np.asarray(merged.pkt_actions),
-                np.asarray(merged.drained.mask),
-                np.asarray(merged.drained.tuple_id),
-                np.asarray(merged.flow_actions),
-                np.asarray(merged.flow_cls))
-            host_s = (enqueue_s + host_extra_s
-                      + (time.perf_counter() - t2))
-            self.stats.record_dispatch(
-                host_s + device_s, packets=n, dispatches=len(rounds),
-                flows=n_flows, new_flows=int(merged.new_flows),
-                evicted=int(merged.evicted), spilled=int(merged.spilled),
-                promoted=int(merged.promoted),
-                padded=self._padded_rows(rounds),
-                host_s=host_s, device_s=device_s)
-            return merged
+            counters = self._complete(
+                out, readback, host_s=part.s + enq.s + host_extra_s,
+                rounds=[c for _, c, _ in merge_outs], packets=n,
+                dispatches=len(rounds), padded=self._padded_rows(rounds))
+            if not merge_outs:
+                return out
+            return out._replace(
+                pkt_actions=jnp.asarray(pkt_actions[0]),
+                **{k: jnp.int32(v) for k, v in counters.items()})
 
         return InflightDispatch(finish, steps=1, packets=n)
 
@@ -358,33 +341,23 @@ class ShardedOctopusPipeline(OctopusPipeline):
                                     packets=self.cfg.batch_size)
         for b in batches:
             self._check_batch(b)
-        t0 = time.perf_counter()
-        parts = [self._partition(b)[0] for b in batches]  # lockstep: 1 round
-        shards, keep, src = (jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
-                                                    *leaves)
-                             for leaves in zip(*parts))
-        self.state, out = self._chunk_fn(self.state, shards, keep, src)
-        enqueue_s = time.perf_counter() - t0
+        st = self.stats
+        with span("octopus.partition", st) as part:
+            parts = [self._partition(b)[0] for b in batches]  # lockstep: 1 round
+        with span("octopus.enqueue", st) as enq:
+            shards, keep, src = (
+                jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *leaves)
+                for leaves in zip(*parts))
+            self.state, out = self._chunk_fn(self.state, shards, keep, src)
         n = L * self.cfg.batch_size
         # parts holds one single-round partition PER STEP — padding is per
         # step, not one multi-round step's worth
         padded = sum(self._padded_rows([p]) for p in parts)
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
-            t1 = time.perf_counter()
-            jax.block_until_ready(out)
-            device_s = time.perf_counter() - t1
-            t2 = time.perf_counter()
-            n_flows = self._chunk_feedback(batches, out)
-            host_s = (enqueue_s + host_extra_s
-                      + (time.perf_counter() - t2))
-            self.stats.record_dispatch(
-                host_s + device_s, packets=n, steps=L, flows=n_flows,
-                new_flows=int(np.asarray(out.new_flows).sum()),
-                evicted=int(np.asarray(out.evicted).sum()),
-                spilled=int(np.asarray(out.spilled).sum()),
-                promoted=int(np.asarray(out.promoted).sum()),
-                padded=padded, host_s=host_s, device_s=device_s)
+            self._complete(out, lambda: self._chunk_rows(batches, out),
+                           host_s=part.s + enq.s + host_extra_s, packets=n,
+                           steps=L, padded=padded)
             return out
 
         return InflightDispatch(finish, steps=L, packets=n)
@@ -424,29 +397,17 @@ class ShardedOctopusPipeline(OctopusPipeline):
         if k.shape != (bucket,):
             raise ValueError(f"keep must have shape ({bucket},), got {k.shape}")
         n = int(k.sum())
-        t0 = time.perf_counter()
-        sb = partition_batch(packets, self.num_shards, keep=k)[0]
-        self.state, out = self._masked_fn(self.state, sb.shards, sb.keep,
-                                          sb.src)
-        t1 = time.perf_counter()
-        jax.block_until_ready((self.state, out))
-        t2 = time.perf_counter()
-        self._warm_buckets.add(bucket)
-
-        n_flows = self._feedback(
-            np.asarray(packets.tuple_hash)[k], np.asarray(out.pkt_actions)[k],
-            np.asarray(out.drained.mask), np.asarray(out.drained.tuple_id),
-            np.asarray(out.flow_actions), np.asarray(out.flow_cls))
-        t3 = time.perf_counter()
-
-        host_s, device_s = (t1 - t0) + (t3 - t2), t2 - t1
-        self.stats.record_dispatch(
-            host_s + device_s, packets=n, flows=n_flows,
-            new_flows=int(out.new_flows),
-            evicted=int(out.evicted), spilled=int(out.spilled),
-            promoted=int(out.promoted),
-            padded=self.num_shards * bucket - n,
-            host_s=host_s, device_s=device_s)
+        st = self.stats
+        with span("octopus.step", st, dispatch=st.dispatches, bucket=bucket):
+            with span("octopus.partition", st) as part:
+                sb = partition_batch(packets, self.num_shards, keep=k)[0]
+            with span("octopus.enqueue", st) as enq:
+                self.state, out = self._masked_fn(self.state, sb.shards,
+                                                  sb.keep, sb.src)
+            self._warm_buckets.add(bucket)
+            self._complete(out, lambda: self._masked_rows(packets, k, out),
+                           host_s=part.s + enq.s, packets=n,
+                           padded=self.num_shards * bucket - n)
         return out
 
     def warmup(self) -> None:
