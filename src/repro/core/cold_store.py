@@ -127,51 +127,55 @@ def _check_policy(policy: str) -> None:
                          f"got {policy!r}")
 
 
-def _choose_slot(cold: ColdState, h: jax.Array) -> jax.Array:
-    """Insert destination for tuple ``h``: its own entry if present (never
-    duplicate), else the first empty candidate, else the candidate with the
-    smaller stamp (tie prefers candidate 1)."""
-    a, b = cold_slots(h, cold.tuple_id.shape[0])
-    occ_a = cold.count[a] > 0
-    occ_b = cold.count[b] > 0
-    match_a = occ_a & (cold.tuple_id[a] == h)
-    match_b = occ_b & (cold.tuple_id[b] == h)
-    victim = jnp.where(cold.stamp[a] <= cold.stamp[b], a, b)
+def _choose_slot(tuple_id: jax.Array, count: jax.Array, stamp: jax.Array,
+                 h: jax.Array, freed: Optional[jax.Array] = None
+                 ) -> jax.Array:
+    """Insert destination for tuple ``h`` in the cold table given by its
+    small leaves: its own entry if present (never duplicate), else the first
+    empty candidate, else the candidate with the smaller stamp (tie prefers
+    candidate 1).  ``freed`` names a slot to read as empty although its
+    leaves are not cleared yet."""
+    a, b = cold_slots(h, tuple_id.shape[0])
+    occ_a = count[a] > 0
+    occ_b = count[b] > 0
+    if freed is not None:
+        occ_a &= a != freed
+        occ_b &= b != freed
+    match_a = occ_a & (tuple_id[a] == h)
+    match_b = occ_b & (tuple_id[b] == h)
+    victim = jnp.where(stamp[a] <= stamp[b], a, b)
     return jnp.where(match_a, a,
                      jnp.where(match_b, b,
                                jnp.where(~occ_a, a,
                                          jnp.where(~occ_b, b, victim))))
 
 
-def _insert_one(cold: ColdState, tid, cnt, ts, feats, ser, siz, pay,
-                do: jax.Array, policy: str) -> ColdState:
-    """Insert one flow record (scalar leaves) when ``do``; a False ``do``
-    scatters to the out-of-range sentinel and is a complete no-op."""
-    C = cold.tuple_id.shape[0]
-    tgt = jnp.where(do, _choose_slot(cold, tid), C)
-    stamp = ts if policy == "age" else cold.tick
-    return cold._replace(
-        tuple_id=cold.tuple_id.at[tgt].set(tid, mode="drop"),
-        count=cold.count.at[tgt].set(cnt, mode="drop"),
-        last_ts=cold.last_ts.at[tgt].set(ts, mode="drop"),
-        features=cold.features.at[tgt].set(feats, mode="drop"),
-        series=cold.series.at[tgt].set(ser, mode="drop"),
-        sizes=cold.sizes.at[tgt].set(siz, mode="drop"),
-        payload=cold.payload.at[tgt].set(pay, mode="drop"),
-        stamp=cold.stamp.at[tgt].set(stamp, mode="drop"),
-        tick=cold.tick + do.astype(jnp.int32),
-    )
+def _last_writer(dst: jax.Array, C: int) -> jax.Array:
+    """``dst`` with every destination that a later entry also writes set to
+    the out-of-range sentinel ``C``, so one vectorised scatter keeps the
+    last writer of each slot, as sequential inserts would."""
+    dup_later = jnp.triu(dst[None, :] == dst[:, None], k=1).any(axis=1)
+    return jnp.where(dup_later, C, dst)
+
+
+def _acting_first(act: jax.Array) -> tuple[jax.Array, jax.Array]:
+    """``(order, n)``: a permutation that puts the ``act`` entries first,
+    keeping their order, and how many there are — a serial walk over
+    ``order[:n]`` makes one trip per acting entry."""
+    return (jnp.argsort(~act, stable=True),
+            act.sum().astype(jnp.int32))
 
 
 def promote_pass(hot: ft.TrackerState, cold: ColdState,
                  packets: ft.PacketBatch,
-                 keep: Optional[jax.Array] = None, *,
-                 policy: str) -> tuple[ft.TrackerState, ColdState, jax.Array]:
+                 keep: Optional[jax.Array] = None, *, policy: str
+                 ) -> tuple[ft.TrackerState, ColdState, jax.Array, jax.Array]:
     """Step 1 of the two-level step: walk the batch's segment heads in
     ascending hot-slot order; where the head tuple is not live in hot but
     present in cold, load the cold entry into the hot slot (spilling a
     displaced occupant into cold first) and free the cold source.  Returns
-    ``(hot, cold, promoted_count)``.
+    ``(hot, cold, promoted_count, walked)``, ``walked`` being the serial
+    trips made.
 
     Runs *before* the merge, so the merge sees the promoted flow as a hit
     and its packet count keeps growing where the single-level tracker would
@@ -193,6 +197,20 @@ def promote_pass(hot: ft.TrackerState, cold: ColdState,
         so it can never be a later head's promotion source);
       * when two displaced occupants land on the same cold slot the later
         insert wins — resolved below with a last-writer mask.
+    The walk visits only the heads that can promote, one trip each: the
+    *candidates*, heads whose tuple is not live in hot and is in cold
+    before the pass, found vectorized on the pre-pass state.  Skipping the
+    rest is exact:
+      * hot is read-only during the walk, so a head that is live in hot
+        stays live;
+      * over the walk a later head's cold entry can only disappear (a freed
+        source, or a victim a displaced occupant evicts), never appear: a
+        displaced occupant's tuple hashes to its own head's hot slot, which
+        no other head owns, so it never equals another head's tuple;
+      * a head that does not promote leaves the carry untouched (nothing
+        freed, nothing inserted, ``tick`` unchanged).
+    A candidate whose entry disappeared mid-walk still takes its trip and
+    promotes nothing.
     The oracle differential in tests/test_cold_store.py pins all of this."""
     _check_policy(policy)
     F = hot.tuple_id.shape[0]
@@ -205,58 +223,54 @@ def promote_pass(hot: ft.TrackerState, cold: ColdState,
     s_slot = slots[order]
     s_hash = packets.tuple_hash[order]
     first = jnp.concatenate([jnp.ones((1,), bool), s_slot[1:] != s_slot[:-1]])
+    head = first & (s_slot < F)
+    fss = jnp.where(s_slot < F, s_slot, 0)
+    # the hot slot's pre-pass occupant; hot is read-only in the walk
+    occ_tid, occ_cnt, occ_ts = (hot.tuple_id[fss], hot.count[fss],
+                                hot.last_ts[fss])
+    hit = (occ_cnt > 0) & (occ_tid == s_hash)
+    s_a, s_b = cold_slots(s_hash, C)
+    in_a = (cold.count[s_a] > 0) & (cold.tuple_id[s_a] == s_hash)
+    in_b = (cold.count[s_b] > 0) & (cold.tuple_id[s_b] == s_hash)
+    walk, n = _acting_first(head & ~hit & (in_a | in_b))
 
-    def body(carry, i):
-        c_tid, c_cnt, c_ts, c_stamp, tick = carry
-        f = s_slot[i]
-        h = s_hash[i]
-        fs = jnp.where(f < F, f, 0)
-        head = first[i] & (f < F)
-        # hot is read-only here: heads own distinct slots, so no iteration
-        # observes another's hot write — hot updates all land in phase 2
-        hit = (hot.count[fs] > 0) & (hot.tuple_id[fs] == h)
-        a, b = cold_slots(h, C)
+    def body(carry):
+        j, c_tid, c_cnt, c_ts, c_stamp, tick, promo, srcs, dsts = carry
+        i = walk[j]
+        h, a, b = s_hash[i], s_a[i], s_b[i]
         in_a = (c_cnt[a] > 0) & (c_tid[a] == h)
         in_b = (c_cnt[b] > 0) & (c_tid[b] == h)
-        promo = head & ~hit & (in_a | in_b)
+        p = in_a | in_b  # false where an earlier trip removed the entry
         src = jnp.where(in_a, a, b)
-        disp = promo & (hot.count[fs] > 0)
-        occupant = (hot.tuple_id[fs], hot.count[fs], hot.last_ts[fs])
+        disp = p & (occ_cnt[i] > 0)
 
         # free the source, then 2-choice-insert the displaced occupant (its
         # probe legitimately sees — and may reuse — the just-freed slot).
-        # All gathers probe the PRE-clear state and adjust for the freed
-        # slot analytically (ox == csrc means empty), so each buffer sees
-        # one gather phase then one scatter phase per iteration — the shape
-        # XLA keeps in place; interleaving gathers between the clear and
-        # insert scatters makes it copy the (C,) leaves every iteration.
-        csrc = jnp.where(promo, src, C)
-        oa, ob = cold_slots(occupant[0], C)
-        occ_a = (c_cnt[oa] > 0) & (oa != csrc)
-        occ_b = (c_cnt[ob] > 0) & (ob != csrc)
-        match_a = occ_a & (c_tid[oa] == occupant[0])
-        match_b = occ_b & (c_tid[ob] == occupant[0])
-        victim = jnp.where(c_stamp[oa] <= c_stamp[ob], oa, ob)
-        choose = jnp.where(match_a, oa,
-                           jnp.where(match_b, ob,
-                                     jnp.where(~occ_a, oa,
-                                               jnp.where(~occ_b, ob, victim))))
-        dst = jnp.where(disp, choose, C)
-        stamp = occupant[2] if policy == "age" else tick
+        # All gathers probe the PRE-clear state and read the freed slot as
+        # empty, so each buffer sees one gather phase then one scatter phase
+        # per iteration — the shape XLA keeps in place; interleaving gathers
+        # between the clear and insert scatters makes it copy the (C,)
+        # leaves every iteration.
+        csrc = jnp.where(p, src, C)
+        dst = jnp.where(disp, _choose_slot(c_tid, c_cnt, c_stamp, occ_tid[i],
+                                           freed=csrc), C)
+        stamp = occ_ts[i] if policy == "age" else tick
         c_tid = c_tid.at[csrc].set(0, mode="drop").at[dst].set(
-            occupant[0], mode="drop")
+            occ_tid[i], mode="drop")
         c_cnt = c_cnt.at[csrc].set(0, mode="drop").at[dst].set(
-            occupant[1], mode="drop")
-        c_ts = c_ts.at[dst].set(occupant[2], mode="drop")
+            occ_cnt[i], mode="drop")
+        c_ts = c_ts.at[dst].set(occ_ts[i], mode="drop")
         c_stamp = c_stamp.at[csrc].set(0, mode="drop").at[dst].set(
             stamp, mode="drop")
-        tick = tick + disp.astype(jnp.int32)
-        return ((c_tid, c_cnt, c_ts, c_stamp, tick), (promo, src, fs, dst))
+        return (j + 1, c_tid, c_cnt, c_ts, c_stamp,
+                tick + disp.astype(jnp.int32), promo.at[i].set(p),
+                srcs.at[i].set(src), dsts.at[i].set(dst))
 
-    carry0 = (cold.tuple_id, cold.count, cold.last_ts, cold.stamp, cold.tick)
-    carry, (promo, srcs, fss, dsts) = lax.scan(
-        body, carry0, jnp.arange(P, dtype=jnp.int32))
-    c_tid, c_cnt, c_ts, c_stamp, tick = carry
+    carry = (jnp.int32(0), cold.tuple_id, cold.count, cold.last_ts,
+             cold.stamp, cold.tick, jnp.zeros((P,), bool),
+             jnp.zeros((P,), jnp.int32), jnp.full((P,), C, jnp.int32))
+    _, c_tid, c_cnt, c_ts, c_stamp, tick, promo, srcs, dsts = lax.while_loop(
+        lambda c: c[0] < n, body, carry)
 
     # phase 2: promoted entries hot[fs] <- pre-pass cold[src].  Gathering
     # from the pre-pass cold is exact — a promotion source still holds its
@@ -269,8 +283,7 @@ def promote_pass(hot: ft.TrackerState, cold: ColdState,
 
     # displaced occupants cold[dst] <- pre-pass hot[fs]; duplicate dst rows
     # resolve to the LAST writer, matching the sequential small-leaf walk
-    dup_later = jnp.triu(dsts[None, :] == dsts[:, None], k=1).any(axis=1)
-    dsts_w = jnp.where(dup_later, C, dsts)
+    dsts_w = _last_writer(dsts, C)
     fss_safe = jnp.where(dsts_w < C, fss, 0)
 
     def store(cold_leaf, hot_leaf):
@@ -290,25 +303,53 @@ def promote_pass(hot: ft.TrackerState, cold: ColdState,
         series=store(cold.series, hot.series),
         sizes=store(cold.sizes, hot.sizes),
         payload=store(cold.payload, hot.payload))
-    return new_hot, new_cold, promo.sum().astype(jnp.int32)
+    return new_hot, new_cold, promo.sum().astype(jnp.int32), n
 
 
 def apply_spills(cold: ColdState, spills: ft.SpillRecords, *,
                  policy: str) -> tuple[ColdState, jax.Array]:
     """Step 3: fold one merge's eviction records into cold, sequentially in
     packet order (later spills may evict earlier ones — exactly the scalar
-    semantics the oracle mirrors).  Returns ``(cold, inserted_count)``."""
+    semantics the oracle mirrors).  Returns ``(cold, inserted_count)``; the
+    serial walk makes one trip per inserted record.
+
+    As in :func:`promote_pass`, the walk carries only the small leaves that
+    :func:`_choose_slot` reads (tuple_id / count / stamp / tick) and records
+    each record's destination; ``last_ts`` and the wide leaves are written
+    afterwards in one scatter each, the later record winning a shared
+    destination.  Exact: what lands in cold comes from the spill records,
+    never from cold, so no trip reads a leaf an earlier trip wrote."""
     _check_policy(policy)
+    C = cold.tuple_id.shape[0]
     P = spills.mask.shape[0]
+    walk, n = _acting_first(spills.mask)
 
-    def body(i, cold):
-        return _insert_one(cold, spills.tuple_id[i], spills.count[i],
-                           spills.last_ts[i], spills.features[i],
-                           spills.series[i], spills.sizes[i],
-                           spills.payload[i], spills.mask[i], policy)
+    def body(carry):
+        j, c_tid, c_cnt, c_stamp, tick, dsts = carry
+        i = walk[j]
+        h = spills.tuple_id[i]
+        dst = _choose_slot(c_tid, c_cnt, c_stamp, h)
+        stamp = spills.last_ts[i] if policy == "age" else tick
+        return (j + 1, c_tid.at[dst].set(h),
+                c_cnt.at[dst].set(spills.count[i]),
+                c_stamp.at[dst].set(stamp), tick + 1, dsts.at[i].set(dst))
 
-    cold = lax.fori_loop(0, P, body, cold)
-    return cold, spills.mask.sum().astype(jnp.int32)
+    carry = (jnp.int32(0), cold.tuple_id, cold.count, cold.stamp, cold.tick,
+             jnp.full((P,), C, jnp.int32))
+    _, c_tid, c_cnt, c_stamp, tick, dsts = lax.while_loop(
+        lambda c: c[0] < n, body, carry)
+    dsts = _last_writer(dsts, C)
+
+    def put(cold_leaf, rec):
+        return cold_leaf.at[dsts].set(rec, mode="drop")
+
+    return cold._replace(
+        tuple_id=c_tid, count=c_cnt, stamp=c_stamp, tick=tick,
+        last_ts=put(cold.last_ts, spills.last_ts),
+        features=put(cold.features, spills.features),
+        series=put(cold.series, spills.series),
+        sizes=put(cold.sizes, spills.sizes),
+        payload=put(cold.payload, spills.payload)), n
 
 
 def scrub_live(cold: ColdState, hot: ft.TrackerState,

@@ -169,14 +169,16 @@ class PipelineStepOutput(NamedTuple):
     evicted: jax.Array  # () int32 — stale flows recycled by collision
     spilled: jax.Array  # () int32 — evictions spilled into the cold store
     promoted: jax.Array  # () int32 — cold entries promoted back into hot
+    cold_walk: jax.Array  # () int32 — serial trips of the cold tier's
+    # promote and spill walks (0 without a cold tier)
     fallback_slots: jax.Array  # () int32 — table slots whose in-batch
     # collision took the segmented tracker's scan fallback
     ready_left: jax.Array  # () int32 — ready flows left undrained (max_ready)
 
 
 # the step counters, read back together in one transfer per dispatch
-COUNTERS = ("new_flows", "evicted", "spilled", "promoted", "fallback_slots",
-            "ready_left")
+COUNTERS = ("new_flows", "evicted", "spilled", "promoted", "cold_walk",
+            "fallback_slots", "ready_left")
 
 
 def _host_outputs(out: PipelineStepOutput) -> tuple[np.ndarray, ...]:
@@ -258,6 +260,7 @@ class PipelineStats:
     evicted: int = 0
     spilled: int = 0  # evictions captured by the cold store (cold_size > 0)
     promoted: int = 0  # cold entries re-established into hot
+    cold_walk: int = 0  # serial trips of the cold tier's two walks
     dispatches: int = 0  # host->device round-trips (chunking lowers it below
     # steps; sharded overflow rounds raise it above)
     padded: int = 0  # dispatched-but-masked lane rows (sharding skew cost)
@@ -275,9 +278,9 @@ class PipelineStats:
                         dispatches: int = 1, flows: int = 0,
                         new_flows: int = 0, evicted: int = 0,
                         spilled: int = 0, promoted: int = 0,
-                        fallback_slots: int = 0, ready_left: int = 0,
-                        padded: int = 0, host_s: float = 0.0,
-                        device_s: float = 0.0) -> None:
+                        cold_walk: int = 0, fallback_slots: int = 0,
+                        ready_left: int = 0, padded: int = 0,
+                        host_s: float = 0.0, device_s: float = 0.0) -> None:
         """Fold one timed dispatch (or fused multi-step chunk) into the
         counters.  ``packets`` must be the real packet count — callers that
         dispatch padded lanes pass the keep-mask total, not the lane shape.
@@ -293,6 +296,7 @@ class PipelineStats:
         self.evicted += evicted
         self.spilled += spilled
         self.promoted += promoted
+        self.cold_walk += cold_walk
         self.fallback_dispatches += fallback_slots > 0
         self.fallback_slots += fallback_slots
         self.ready_left += ready_left
@@ -469,26 +473,27 @@ class OctopusPipeline:
                keep: Optional[jax.Array] = None, *, fallback: str = "auto"):
         """Step 2 only: merge one (optionally masked) microbatch into the
         tracker under ``cfg.tracker``.  Returns ``(state, new_flows,
-        evicted, spilled, promoted, fallback_slots)`` — the merge half of the
-        lane contract,
+        evicted, spilled, promoted, cold_walk, fallback_slots)`` — the
+        merge half of the lane contract, its counters in ``COUNTERS`` order,
         dispatched on its own by the sharded pipeline's overflow rounds.
         ``fallback`` is forwarded to the segmented tracker's collision
         branch (vmapped callers hoist it).
 
-        In hot-only mode the state is a plain tracker bank, spills/promotes
-        are constant zero, and the traced merge is identical to the
-        single-level pipeline.  With ``cold_size > 0`` the two-level step
-        semantics documented in :mod:`repro.core.cold_store` run around the
-        same merge: promote -> merge (with spill records) -> spill -> scrub."""
+        In hot-only mode the state is a plain tracker bank, spills, promotes
+        and cold-walk trips are constant zero, and the traced merge is
+        identical to the single-level pipeline.  With ``cold_size > 0`` the
+        two-level step semantics documented in :mod:`repro.core.cold_store`
+        run around the same merge: promote -> merge (with spill records) ->
+        spill -> scrub."""
         zero = jnp.int32(0)
         if not self.cfg.cold_size:
             with jax.named_scope("track.merge"):
                 state, new, ev, fb = self._merge(state, packets, keep,
                                                  fallback=fallback)
-            return state, new, ev, zero, zero, fb
+            return state, new, ev, zero, zero, zero, fb
         hot, cold = state.hot, state.cold
         with jax.named_scope("track.promote"):
-            hot, cold, promoted = cold_store.promote_pass(
+            hot, cold, promoted, walked = cold_store.promote_pass(
                 hot, cold, packets, keep, policy=self.cfg.cold_policy)
         with jax.named_scope("track.merge"):
             hot, new, ev, fb, spills = self._merge(hot, packets, keep,
@@ -500,7 +505,7 @@ class OctopusPipeline:
         with jax.named_scope("track.scrub"):
             cold = cold_store.scrub_live(cold, hot, packets, keep)
         return (cold_store.TwoLevelState(hot, cold), new, ev, spilled,
-                promoted, fb)
+                promoted, walked + spilled, fb)
 
     def _lane_core(self, state, packets: ft.PacketBatch,
                    keep: Optional[jax.Array] = None, *,
@@ -514,8 +519,7 @@ class OctopusPipeline:
         hash-partitioned lanes.  Draining always happens on the hot bank —
         cold flows re-enter the hot table through promotion before they can
         emit."""
-        state, new_flows, evicted, spilled, promoted, fallback_slots = \
-            self._track(state, packets, keep, fallback=fallback)
+        state, *counts = self._track(state, packets, keep, fallback=fallback)
         hot = state.hot if self.cfg.cold_size else state
         with jax.named_scope("drain"):
             ready = ft.ready_mask(hot, top_n=self.cfg.top_n).sum()
@@ -532,11 +536,7 @@ class OctopusPipeline:
             flow_actions=flow_actions,
             flow_cls=flow_cls,
             flow_scores=flow_scores,
-            new_flows=new_flows,
-            evicted=evicted,
-            spilled=spilled,
-            promoted=promoted,
-            fallback_slots=fallback_slots,
+            **dict(zip(COUNTERS, counts)),
             ready_left=ready_left,
         )
 

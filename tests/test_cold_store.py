@@ -4,8 +4,10 @@ with spill capture -> sequential cold inserts -> scrub -> drain), spill-record
 parity between the scan and segmented trackers, hot-only bit-equivalence
 (``cold_size > 0`` with collision-free traffic == single-level pipeline),
 eviction-policy unit tests, a spill/promote roundtrip proving flow history
-survives eviction, and shard/no-shard equivalence with per-lane cold banks."""
+survives eviction, shard/no-shard equivalence with per-lane cold banks, and
+the compacted walks' edge cases (one serial trip per acting record)."""
 import copy
+import functools
 from dataclasses import replace
 
 import jax
@@ -74,7 +76,7 @@ class TwoLevelOracle(OracleTracker):
         return None
 
     def _cold_insert(self, entry):
-        """Mirror of _choose_slot + _insert_one: own entry -> first empty
+        """Mirror of _choose_slot + one spill insert: own entry -> first empty
         candidate -> smaller stamp (tie prefers candidate a)."""
         h = entry["tuple_id"]
         a, b = cold_store.cold_slots_scalar(h, self.cold_size)
@@ -380,7 +382,7 @@ def test_cold_attached_is_bit_identical_on_collision_free_traffic(params):
     for _ in range(20):
         out0, out1 = base.step(g0.next_batch()), two.step(g1.next_batch())
         for name, a, b in zip(out0._fields, out0, out1):
-            if name in ("spilled", "promoted"):
+            if name in ("spilled", "promoted", "cold_walk"):
                 continue
             jax.tree_util.tree_map(
                 lambda x, y: np.testing.assert_array_equal(
@@ -458,3 +460,195 @@ def test_sharded_two_level_matches_single_lane(params):
     assert int(lane1.hot.count.sum()) == 0
     assert ref.stats.spilled == sh.stats.spilled > 0
     assert ref.stats.promoted == sh.stats.promoted > 0
+
+
+# ---------------------------------------------------------------------------
+# Compacted walks: one serial trip per acting record, exact on crafted edges
+# ---------------------------------------------------------------------------
+
+WALK_F, WALK_C = 16, 8  # hot and cold sizes of the crafted walk cases
+_WALK_H = np.arange(1, 1 << 17, dtype=np.int64)
+
+
+@functools.cache
+def _walk_slots():
+    """Hot slot and both cold candidates of every hash in ``_WALK_H``."""
+    h = jnp.asarray(_WALK_H, jnp.int32)
+    a, b = cold_store.cold_slots(h, WALK_C)
+    return (np.asarray(ft.hash_slot(h, WALK_F)), np.asarray(a),
+            np.asarray(b))
+
+
+def _pick(taken, *, hot=None, a=None, pair=None):
+    """The first unused hash with the given hot slot, first cold candidate
+    and/or unordered cold candidate pair."""
+    hs, ca, cb = _walk_slots()
+    ok = ~np.isin(_WALK_H, list(taken))
+    if hot is not None:
+        ok &= hs == hot
+    if a is not None:
+        ok &= ca == a
+    if pair is not None:
+        ok &= ((ca == pair[0]) & (cb == pair[1])) | (
+            (ca == pair[1]) & (cb == pair[0]))
+    h = int(_WALK_H[np.flatnonzero(ok)[0]])
+    taken.add(h)
+    return h
+
+
+def _walk_batch(hashes, t0):
+    """Packets with sizes and payload that differ per flow and packet, so
+    every wide leaf tells one record from another."""
+    h = np.asarray(hashes, np.int64)
+    ts = t0 + np.arange(len(hashes))
+    b = make_batch(hashes, ts.tolist(), (40 + (h * 7 + ts) % 1400).tolist())
+    pay = (h[:, None] * 3 + ts[:, None] + np.arange(16)[None]) % 256
+    return b._replace(payload=jnp.asarray(pay, jnp.int32))
+
+
+def _walk_idle():
+    taken: set = set()
+    f = [_pick(taken, hot=s) for s in (1, 4, 9, 13)]
+    return [(_walk_batch(f + f, 1), (0, 0, 0)),
+            (_walk_batch(f[::-1] + f, 10), (0, 0, 0))]
+
+
+def _walk_all_spill():
+    """Every packet of the second batch evicts its slot's occupant."""
+    taken: set = set()
+    c1, a1, a2 = (_pick(taken, hot=3) for _ in range(3))
+    c2, b1, b2 = (_pick(taken, hot=11) for _ in range(3))
+    return [(_walk_batch([c1, c2] * 4, 1), (0, 0, 0)),
+            (_walk_batch([a1, b1, a2, b2] * 2, 10), (8, 0, 8))]
+
+
+def _walk_spill_evicts_spill():
+    """Y's spill evicts X's, spilled earlier in the same batch: both land on
+    cold slot p, Y last, so Y wins in every leaf."""
+    taken: set = set()
+    p, q = 2, 5
+    x = _pick(taken, hot=1, a=p, pair=(p, q))
+    y = _pick(taken, hot=6, pair=(p, q))
+    w = _pick(taken, hot=12, a=q)
+    x2, y2, w2 = (_pick(taken, hot=s) for s in (1, 6, 12))
+    return [(_walk_batch([x, y, w, x, y, y, x, w], 1), (0, 0, 0)),
+            # W spills with stamp 100 > X's 7
+            (_walk_batch([w] + [w2] * 7, 100), (1, 0, 1)),
+            (_walk_batch([x2, y2] * 4, 200), (2, 0, 2))]
+
+
+def _walk_promote_shrinks():
+    """Two pre-pass candidates, A (hot slot 2) then B (hot slot 9): A's
+    displaced occupant O evicts B's cold source (B's stamp 5 < R's 8), so
+    the second trip finds nothing to promote."""
+    taken: set = set()
+    cb, r, ca = 1, 6, 3
+    o = _pick(taken, hot=2, pair=(cb, r))
+    a = _pick(taken, hot=2, a=ca)
+    b = _pick(taken, hot=9, a=cb)
+    b2 = _pick(taken, hot=9)
+    rr = _pick(taken, hot=14, a=r)
+    r2 = _pick(taken, hot=14)
+    return [(_walk_batch([a, b, rr, a, b, rr, rr, rr], 1), (0, 0, 0)),
+            (_walk_batch([o, b2, r2] * 2 + [o, b2], 100), (3, 0, 3)),
+            # walk 2 candidates + 1 spill (B evicts B2); promoted only A
+            (_walk_batch([a, b] * 4, 200), (1, 1, 3))]
+
+
+def _walk_lanes():
+    """Two vmapped lanes: lane 0 a collision storm, lane 1 almost idle."""
+    S = 2
+    rng = np.random.default_rng(3)
+    hs, _, _ = _walk_slots()
+    lane = shard_of(_WALK_H, S)
+    storm = [int(h) for s in (2, 7, 13)
+             for h in _WALK_H[(lane == 0) & (hs == s)][:4]]
+    calm = [int(h) for s in (4, 10)
+            for h in _WALK_H[(lane == 1) & (hs == s)][:2]]
+    batches = []
+    for k in range(8):
+        hashes = np.concatenate([rng.choice(storm, 13),
+                                 rng.choice(calm, 3)])
+        batches.append((_walk_batch(hashes.tolist(), 1 + 20 * k), None))
+    return batches
+
+
+WALK_CASES = {"idle": _walk_idle, "all_spill": _walk_all_spill,
+              "spill_evicts_spill": _walk_spill_evicts_spill,
+              "promote_shrinks": _walk_promote_shrinks,
+              "lanes": _walk_lanes}
+
+
+def _walk_candidates(oracle, batch_dicts):
+    """Pre-pass promote candidates: segment heads whose tuple is not live in
+    hot and is in cold."""
+    heads: dict = {}
+    for pkt in batch_dicts:
+        heads.setdefault(oracle.slot_of(pkt["tuple_hash"]), pkt["tuple_hash"])
+    return sum(oracle._cold_find(h) is not None
+               for s, h in heads.items()
+               if oracle.slots.get(s, {}).get("tuple_id") != h)
+
+
+def _assert_every_leaf_equal(state, oracle):
+    """:func:`assert_two_level_state_equal` plus last_ts and every wide
+    leaf of both tiers."""
+    assert_two_level_state_equal(state, oracle)
+    for tier, table in (("hot", oracle.slots), ("cold", oracle.cold)):
+        leaves = getattr(state, tier)
+        for s, e in table.items():
+            assert int(leaves.last_ts[s]) == e["last_ts"], (tier, s)
+            for k in ("series", "sizes", "payload"):
+                np.testing.assert_array_equal(
+                    np.asarray(getattr(leaves, k)[s]),
+                    np.asarray(e[k], np.int32), err_msg=f"{tier}[{s}].{k}")
+
+
+@pytest.mark.parametrize("case", list(WALK_CASES))
+def test_compacted_walks_match_oracle(params, case):
+    """The promote and spill walks make one trip per acting record
+    (``cold_walk`` = pre-pass promote candidates + spill records) and stay
+    bit-exact to the sequential oracle: no acting record, all P records
+    acting, a spill evicting an earlier spill of its batch, a candidate
+    losing its cold source mid-walk, and vmapped lanes whose trip counts
+    differ."""
+    steps = WALK_CASES[case]()
+    S = 2 if case == "lanes" else 1
+    P = steps[0][0].ts.shape[0]
+    cfg = PipelineConfig(batch_size=P, max_ready=2 * S,
+                         flow_model="transformer", table_size=WALK_F,
+                         top_n=16, top_k=15, pay_bytes=16, cold_size=WALK_C)
+    if S == 1:
+        pipe = OctopusPipeline(params["mlp"], params["transformer"], cfg)
+    else:
+        pipe = ShardedOctopusPipeline(params["mlp"], params["transformer"],
+                                      cfg, num_shards=S, backend="vmap")
+    oracles = [TwoLevelOracle(WALK_F, WALK_C, top_n=16, top_k=15,
+                              pay_bytes=16) for _ in range(S)]
+    lane_walks = []
+    for batch, want in steps:
+        pkts = batch_as_dicts(batch)
+        got = np.zeros(3, int)  # spilled, promoted, walked
+        walks = []
+        for k, orc in enumerate(oracles):
+            mine = [d for d in pkts if shard_of(d["tuple_hash"], S) == k]
+            s0, p0 = orc.spilled, orc.promoted
+            cands = _walk_candidates(orc, mine)
+            orc.step_batch(mine, cfg.max_ready // S)
+            d = (orc.spilled - s0, orc.promoted - p0)
+            walks.append(cands + d[0])
+            got += (d[0], d[1], cands + d[0])
+        lane_walks.append(walks)
+        out = pipe.step(batch)
+        assert (int(out.spilled), int(out.promoted),
+                int(out.cold_walk)) == tuple(got)
+        if want is not None:
+            assert tuple(got) == want
+    for k, orc in enumerate(oracles):
+        lane = pipe.state if S == 1 else jax.tree_util.tree_map(
+            lambda a: a[k], pipe.state)
+        _assert_every_leaf_equal(lane, orc)
+    assert pipe.stats.cold_walk == sum(map(sum, lane_walks))
+    if case == "lanes":
+        assert any(w[0] != w[1] for w in lane_walks)
+        assert all(w[0] > 0 for w in lane_walks[1:])

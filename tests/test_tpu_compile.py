@@ -9,6 +9,7 @@ The topology is described inside a module fixture — never while a module is
 imported — and every test here skips when it cannot be described.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -99,3 +100,11 @@ def test_pipeline_step_compiles(one_chip, use_pallas, cold_size):
         _abstract(pipe._zero_batch(), one_chip)).compile()
     text = compiled.as_text()
     assert ("tpu_custom_call" in text) == use_pallas
+    if cold_size:
+        # the cold tier's promote and spill walks are while loops that carry
+        # only (C,) bookkeeping leaves; every wide cold leaf (features,
+        # series, sizes, payload) is scattered outside them
+        whiles = [line for line in text.splitlines()
+                  if re.search(r"= \(.*\) while\(", line)]
+        assert sum(f"s32[{cold_size}]" in line for line in whiles) >= 2
+        assert not any(f"s32[{cold_size}," in line for line in whiles)

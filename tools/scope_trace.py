@@ -31,7 +31,8 @@ sys.path.insert(0, str(ROOT))
 from bench import harness, scopes, trace  # noqa: E402
 
 # pipeline counters read beside the harness's (0 where the program lacks them)
-COUNTERS = ("dispatches", "fallback_dispatches", "fallback_slots", "ready_left")
+COUNTERS = ("dispatches", "fallback_dispatches", "fallback_slots", "ready_left",
+            "spilled", "promoted", "cold_walk")
 KEEP_DISPATCHES = 3
 
 
