@@ -244,7 +244,10 @@ class PipelineStats:
     rows — those are deliberately not packets, so ``pkt_per_s`` stays an
     honest wire-rate), ``steps`` is pipeline steps (a chunked dispatch
     advances ``scan_len`` of them), ``dispatches`` is host->device round
-    trips (a multi-round sharded step can issue several).
+    trips (a multi-round sharded step makes several).  A sharded
+    dispatch also adds ``lane_rows_max``, the kept rows of its fullest lane:
+    ``packets / (packets + padded)`` is the lanes' fill and ``num_shards *
+    lane_rows_max / packets`` their skew (1.0 when the lanes are even).
 
     Beyond the aggregate means (``dispatch_us``/``step_us``), every timed
     dispatch region also lands one sample in a bounded
@@ -264,6 +267,8 @@ class PipelineStats:
     dispatches: int = 0  # host->device round-trips (chunking lowers it below
     # steps; sharded overflow rounds raise it above)
     padded: int = 0  # dispatched-but-masked lane rows (sharding skew cost)
+    lane_rows_max: int = 0  # the fullest lane's kept rows, summed over
+    # dispatches (sharded pipelines; host-side, from the hash partition)
     fallback_dispatches: int = 0  # dispatches whose merge took the fallback
     fallback_slots: int = 0  # table slots that took it, summed
     ready_left: int = 0  # ready flows left undrained after each drain, summed
@@ -280,6 +285,7 @@ class PipelineStats:
                         spilled: int = 0, promoted: int = 0,
                         cold_walk: int = 0, fallback_slots: int = 0,
                         ready_left: int = 0, padded: int = 0,
+                        lane_rows_max: int = 0,
                         host_s: float = 0.0, device_s: float = 0.0) -> None:
         """Fold one timed dispatch (or fused multi-step chunk) into the
         counters.  ``packets`` must be the real packet count — callers that
@@ -301,6 +307,7 @@ class PipelineStats:
         self.fallback_slots += fallback_slots
         self.ready_left += ready_left
         self.padded += padded
+        self.lane_rows_max += lane_rows_max
         self.host_s += host_s
         self.device_s += device_s
         self.lat.add(dt * 1e6)  # one sample per timed region (us)

@@ -44,6 +44,18 @@ merge-only rounds ahead of the fused drain step
 (:func:`repro.data.traffic.partition_batch` splits each lane's FIFO into
 capacity-sized windows; the tracker merge composes sequentially, so the
 result stays bit-exact and the drain still happens once per global batch).
+The served path (``step_masked``, one padded bucket per dispatch) always
+partitions at full bucket capacity: each lane gets ``bucket`` rows, about
+``bucket / num_shards`` of them kept, so every lane's merge runs over the
+whole bucket.  ``PipelineStats.lane_rows_max`` adds each dispatch's fullest
+lane's kept rows, counted on the host (lane fill ``packets / (packets +
+padded)``, skew ``num_shards * lane_rows_max / packets``).
+
+Measurement: the merge of the lane outputs (:meth:`_merge_out`) runs under
+the ``lanes.merge`` named scope, outside the ``shard_map``; under it sit
+the cross-device collectives the merge needs (verdicts and source rows
+gathered for the scatter back to batch order, counters summed), so the
+device trace names their time.  No collective touches the carried banks.
 """
 from __future__ import annotations
 
@@ -155,9 +167,12 @@ class ShardedOctopusPipeline(OctopusPipeline):
             out = fn(*jax.tree_util.tree_map(lambda x: x[0], args))
             return jax.tree_util.tree_map(lambda x: x[None], out)
 
+        # the lane body is the single-lane core, written without manual-axis
+        # types: its cold-tier walks start their carries from constants, which
+        # the varying-axes check would refuse against the lane-varying state
         spec = shd.lanes_spec()
         return jax.shard_map(body, mesh=self.mesh, in_specs=spec,
-                             out_specs=spec)
+                             out_specs=spec, check_vma=False)
 
     def _merge_out(self, outs: PipelineStepOutput, src: jax.Array, *,
                    batch: Optional[int] = None) -> PipelineStepOutput:
@@ -168,17 +183,19 @@ class ShardedOctopusPipeline(OctopusPipeline):
         ``max_ready`` emission.  ``batch`` overrides the scatter target size
         for bucket-shaped masked steps (default: the config batch)."""
         B = self.cfg.batch_size if batch is None else batch
-        pkt_actions = jnp.zeros((B,), jnp.int32).at[src.reshape(-1)].set(
-            outs.pkt_actions.reshape(-1), mode="drop")
         flat = lambda a: a.reshape((self.cfg.max_ready,) + a.shape[2:])
-        return PipelineStepOutput(
-            pkt_actions=pkt_actions,
-            drained=jax.tree_util.tree_map(flat, outs.drained),
-            flow_actions=flat(outs.flow_actions),
-            flow_cls=flat(outs.flow_cls),
-            flow_scores=flat(outs.flow_scores),
-            **{k: getattr(outs, k).sum().astype(jnp.int32) for k in COUNTERS},
-        )
+        with jax.named_scope("lanes.merge"):
+            pkt_actions = jnp.zeros((B,), jnp.int32).at[src.reshape(-1)].set(
+                outs.pkt_actions.reshape(-1), mode="drop")
+            return PipelineStepOutput(
+                pkt_actions=pkt_actions,
+                drained=jax.tree_util.tree_map(flat, outs.drained),
+                flow_actions=flat(outs.flow_actions),
+                flow_cls=flat(outs.flow_cls),
+                flow_scores=flat(outs.flow_scores),
+                **{k: getattr(outs, k).sum().astype(jnp.int32)
+                   for k in COUNTERS},
+            )
 
     # ------------------------------------------------------------ traced cores
     def _lanes_cond(self, make_lane, states, shards, keep):
@@ -255,6 +272,15 @@ class ShardedOctopusPipeline(OctopusPipeline):
             else self.lane_batch
         return partition_batch(packets, self.num_shards, lane_batch=lane_batch)
 
+    def _lane_rows_max(self, packets: ft.PacketBatch,
+                       keep: Optional[np.ndarray] = None) -> int:
+        """Kept rows of the fullest lane, counted on the host from the
+        tuple hashes the partition reads (no device readback)."""
+        lane = shard_of(np.asarray(packets.tuple_hash), self.num_shards)
+        if keep is not None:
+            lane = lane[keep]
+        return int(np.bincount(lane, minlength=self.num_shards).max())
+
     def _padded_rows(self, rounds: Sequence[ShardedBatch]) -> int:
         """Masked lane rows this step will dispatch.  Pure arithmetic —
         conservation guarantees the kept rows across all rounds are exactly
@@ -273,6 +299,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
         st = self.stats
         with span("octopus.partition", st) as part:
             rounds = self._partition(packets)
+            fullest = self._lane_rows_max(packets)
         with span("octopus.enqueue", st) as enq:
             merge_outs = []
             for sb in rounds[:-1]:
@@ -303,7 +330,8 @@ class ShardedOctopusPipeline(OctopusPipeline):
             counters = self._complete(
                 out, readback, host_s=part.s + enq.s + host_extra_s,
                 rounds=[c for _, c, _ in merge_outs], packets=n,
-                dispatches=len(rounds), padded=self._padded_rows(rounds))
+                dispatches=len(rounds), padded=self._padded_rows(rounds),
+                lane_rows_max=fullest)
             if not merge_outs:
                 return out
             return out._replace(
@@ -344,6 +372,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
         st = self.stats
         with span("octopus.partition", st) as part:
             parts = [self._partition(b)[0] for b in batches]  # lockstep: 1 round
+            fullest = sum(self._lane_rows_max(b) for b in batches)
         with span("octopus.enqueue", st) as enq:
             shards, keep, src = (
                 jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *leaves)
@@ -357,7 +386,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
         def finish(host_extra_s: float) -> PipelineStepOutput:
             self._complete(out, lambda: self._chunk_rows(batches, out),
                            host_s=part.s + enq.s + host_extra_s, packets=n,
-                           steps=L, padded=padded)
+                           steps=L, padded=padded, lane_rows_max=fullest)
             return out
 
         return InflightDispatch(finish, steps=L, packets=n)
@@ -401,13 +430,15 @@ class ShardedOctopusPipeline(OctopusPipeline):
         with span("octopus.step", st, dispatch=st.dispatches, bucket=bucket):
             with span("octopus.partition", st) as part:
                 sb = partition_batch(packets, self.num_shards, keep=k)[0]
+                fullest = self._lane_rows_max(packets, k)
             with span("octopus.enqueue", st) as enq:
                 self.state, out = self._masked_fn(self.state, sb.shards,
                                                   sb.keep, sb.src)
             self._warm_buckets.add(bucket)
             self._complete(out, lambda: self._masked_rows(packets, k, out),
                            host_s=part.s + enq.s, packets=n,
-                           padded=self.num_shards * bucket - n)
+                           padded=self.num_shards * bucket - n,
+                           lane_rows_max=fullest)
         return out
 
     def warmup(self) -> None:

@@ -1,6 +1,7 @@
-"""``tools/scope_trace.py``: the pruning of a kept trace, and one traced run
-of a benchmark cell at a few hundred flows on the CPU through the harness
-with the tool's hooks in place (so a change to the harness that breaks them
+"""``tools/scope_trace.py``: the pruning of a kept trace, the per-chip and
+lane numbers on hand-made events and counters, and one traced run of a
+benchmark cell at a few hundred flows on the CPU through the harness with
+the tool's hooks in place (so a change to the harness that breaks them
 shows here)."""
 import copy
 import sys
@@ -93,4 +94,42 @@ def test_traced_run_through_the_harness_on_the_cpu(monkeypatch):
     assert 0 <= sc["counters_untraced"]["fallback_dispatches"] <= n
     # no device planes on the CPU: nothing for the device reductions
     assert sc["dispatches"] == 0 and sc["device_scopes"] == {}
-    assert "kept" not in sc
+    assert sc["chip_busy"] == {"planes": {}, "spread": None}
+    assert "kept" not in sc and "lanes" not in sc  # one lane: no lane numbers
+
+
+def test_chip_busy_per_plane_and_spread():
+    d1 = "/device:TPU:1"
+    events = [ev(D, "XLA Ops", "a", 0, 600), ev(D, "XLA Ops", "b", 300, 500),
+              ev(d1, "XLA Ops", "c", 100, 200), ev(d1, "XLA Ops", "d", 900, 300)]
+    got = scope_trace.chip_busy(events, 0, 1000)
+    assert got["planes"] == {D: 80.0, d1: 30.0}  # d's end is cut at the window
+    assert got["spread"] == 50.0
+
+
+def test_merge_seconds_count_the_merge_scope_once_per_plane():
+    d1 = "/device:TPU:1"
+    merge = M + "lanes.merge/scatter"
+    events = [ev(D, "XLA Ops", "a", 0, 400, merge),
+              ev(D, "XLA Ops", "b", 100, 100, merge),  # nested: counts once
+              ev(D, "XLA Ops", "c", 500, 300, M + "shard_map/track.merge"),
+              ev(d1, "XLA Ops", "d", 0, 200, merge),
+              ev(d1, "XLA Ops", "e", 300, 100, M + "not.lanes.merge")]
+    assert scope_trace.named_seconds(events, 0, 1000, "lanes.merge") == \
+        pytest.approx((400 + 200) / 2 / 1e9)
+
+
+def test_lane_numbers_from_counters_and_spans():
+    red = {"dispatches": 10,
+           "counters_untraced": {"dispatches": 20, "packets": 20 * 1024,
+                                 "padded": 20 * 3 * 1024, "lane_rows_max": 20 * 300},
+           "spans_untraced": {"octopus.partition": [20, 0.030]}}
+    got = scope_trace.lane_numbers(red, 4, 0.004)
+    assert got["lane_fill"] == 25.0
+    assert got["lane_skew"] == pytest.approx(4 * 300 / 1024)
+    assert got["merge_ms_per_dispatch"] == pytest.approx(0.4)
+    assert got["partition_ms_per_dispatch"] == pytest.approx(1.5)
+    assert got["scatter_ms_per_dispatch"] == 0.0  # the served path never scatters
+    # a program without the counter reads no skew
+    red["counters_untraced"]["lane_rows_max"] = 0
+    assert scope_trace.lane_numbers(red, 4, 0.0)["lane_skew"] is None

@@ -451,6 +451,32 @@ def test_step_many_dispatches_every_overflow_round(params):
     assert sh.stats.dispatches == 4  # the rounds actually dispatched
 
 
+@pytest.mark.parametrize("hashes", [[4] * 12 + [1, 2, 3, 5], list(range(16))],
+                         ids=["skewed", "even"])
+def test_lane_rows_max_counts_the_fullest_lane(params, hashes):
+    """``lane_rows_max`` adds each dispatch's fullest lane's kept rows, as
+    the hash partition lays them out, on the masked and the fused path."""
+    cfg = PipelineConfig(batch_size=16, max_ready=4, flow_model="cnn",
+                         table_size=64)
+    sh = ShardedOctopusPipeline(params["mlp"], params["cnn"], cfg,
+                                num_shards=4)
+    batch = make_batch(hashes, list(range(1, 17)))
+    keep = np.arange(16) % 5 != 0
+
+    def fullest(k):
+        rows = np.asarray(partition_batch(batch, 4, keep=k)[0].keep)
+        return int(rows.sum(axis=1).max())
+
+    sh.step_masked(batch, keep)
+    assert sh.stats.lane_rows_max == fullest(keep)
+    sh.step(batch)
+    assert sh.stats.lane_rows_max == fullest(keep) + fullest(None)
+    st = sh.stats
+    skew = 4 * st.lane_rows_max / st.packets
+    assert (skew == 1.0) == (hashes == list(range(16)))
+    assert st.packets / (st.packets + st.padded) == st.packets / (4 * 16 * 2)
+
+
 def test_sharded_step_rejects_wrong_batch_size(params):
     cfg = PipelineConfig(batch_size=8, max_ready=2, flow_model="cnn",
                          table_size=64)

@@ -13,22 +13,25 @@ import re
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 import pytest
-from jax.sharding import SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
 
+from repro.distributed import sharding as shd
 from repro.kernels.arype_matmul import arype_matmul
 from repro.kernels.flow_features.ops import META_WIDTH, fold_features
 from repro.kernels.vpe_smallmm import vpe_matmul
 from repro.models import paper_models
 from repro.runtime import RuntimeConfig
-from repro.serving import OctopusPipeline, PipelineConfig
+from repro.serving import (OctopusPipeline, PipelineConfig,
+                           ShardedOctopusPipeline, sharded)
 
 TABLE = 8192  # the paper's flow table
 BATCH = 256
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
 
@@ -41,8 +44,19 @@ def one_chip():
     # cache without that chip; keep the cache out of these compiles
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
-    yield SingleDeviceSharding(topo.devices[0])
+    yield topo
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The ``lanes`` mesh over the four described chips."""
+    return Mesh(np.asarray(topo.devices), (shd.LANES_AXIS,))
 
 
 def _sds(sharding, shape, dtype=jnp.int32):
@@ -108,3 +122,49 @@ def test_pipeline_step_compiles(one_chip, use_pallas, cold_size):
                   if re.search(r"= \(.*\) while\(", line)]
         assert sum(f"s32[{cold_size}]" in line for line in whiles) >= 2
         assert not any(f"s32[{cold_size}," in line for line in whiles)
+
+
+COLLECTIVE = re.compile(r"= .*\b(all-gather|all-reduce|all-to-all|collective-permute"
+                        r"|reduce-scatter)(-start)?\(")
+
+
+def test_four_lane_step_compiles(four_chips, monkeypatch):
+    """The served four-lane step of ``ids-cnn-x4`` (bucket 1024, a hot table
+    and a cold tier per lane, one lane per chip).  Each lane's banks stay on
+    its chip: no collective moves a table or cold-tier leaf.  The ones the
+    merge needs (verdicts back to batch order, the counters' sums) are
+    named by its ``lanes.merge`` scope."""
+    bucket, cold = 1024, 131072
+    fresh = ShardedOctopusPipeline._fresh_state
+
+    def abstract_state(self):  # shapes only: nothing lives on a described chip
+        mesh, self.mesh = self.mesh, None
+        try:
+            return jax.eval_shape(lambda: fresh(self))
+        finally:
+            self.mesh = mesh
+
+    monkeypatch.setattr(sharded, "make_lanes_mesh", lambda n: four_chips)
+    monkeypatch.setattr(ShardedOctopusPipeline, "_fresh_state", abstract_state)
+    cfg = PipelineConfig(batch_size=bucket, max_ready=256, flow_model="cnn",
+                         table_size=TABLE, cold_size=cold)
+    pipe = ShardedOctopusPipeline(
+        paper_models.init_paper_model("mlp", jax.random.PRNGKey(0)),
+        paper_models.init_paper_model("cnn", jax.random.PRNGKey(1)), cfg,
+        num_shards=4, backend="shard_map",
+        config=RuntimeConfig(use_pallas=False, interpret=False))
+
+    def lanes(tree):
+        return jax.tree_util.tree_map(lambda a: _sds(
+            NamedSharding(four_chips, shd.lanes_spec(a.ndim - 1)), a.shape,
+            a.dtype), tree)
+
+    zb = jax.eval_shape(lambda: pipe._zero_parts(bucket))
+    text = pipe._masked_fn.lower(lanes(pipe.state), lanes(zb.shards),
+                                 lanes(zb.keep), lanes(zb.src)).compile().as_text()
+    collectives = [line for line in text.splitlines() if COLLECTIVE.search(line)]
+    assert collectives
+    wide = re.compile(rf"[\[,]({TABLE}|{cold})[\],]")
+    for line in collectives:
+        assert not wide.search(line.split("metadata=", 1)[0]), line
+        assert "/lanes.merge/" in line, line

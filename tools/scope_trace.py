@@ -9,6 +9,12 @@ each operation's scope with and without the scope of its user), the
 operations that took their user's, idle gaps named by the innermost
 program span, the span tables of the window before the profiler started
 and while it ran, and the pipeline counters before it started.
+``chip_busy`` gives each device plane's busy share of the traced window and
+the spread between the busiest and the idlest chip.  A cell with lanes adds
+``lanes``: their fill (kept rows over dispatched lane rows), their skew
+(lanes times the fullest lane's kept rows over the kept rows, 1.0 when
+even), and ms per dispatch of the ``lanes.merge`` scope on the device and
+of the ``octopus.partition`` and ``octopus.scatter`` host spans.
 
     python3 tools/scope_trace.py --workload ids-cnn.churn.sat --seed 7 --seconds 30
 
@@ -32,7 +38,9 @@ from bench import harness, scopes, trace  # noqa: E402
 
 # pipeline counters read beside the harness's (0 where the program lacks them)
 COUNTERS = ("dispatches", "fallback_dispatches", "fallback_slots", "ready_left",
-            "spilled", "promoted", "cold_walk")
+            "spilled", "promoted", "cold_walk", "packets", "padded", "lane_rows_max")
+# the sharded step's merge across lanes (serving/sharded.py)
+MERGE_SCOPE = "lanes.merge"
 KEEP_DISPATCHES = 3
 
 
@@ -67,6 +75,52 @@ def prune(events: list[dict], t0: int, t1: int) -> list[dict]:
     out.append({"plane": host, "line": "python3", "name": trace.WINDOW_SPAN,
                 "start_ns": t0, "dur_ns": t1 - t0})
     return out
+
+
+def chip_busy(events: list[dict], t0: int, t1: int) -> dict:
+    """{"planes": {device plane: busy % of [t0, t1)}, "spread": busiest
+    minus idlest, in points}."""
+    busy = {p: 100.0 * sum(f - s for s, f in trace.busy_intervals(events, p, t0, t1))
+            / (t1 - t0) for p in trace.device_planes(events)}
+    return {"planes": busy,
+            "spread": max(busy.values()) - min(busy.values()) if busy else None}
+
+
+def named_seconds(events: list[dict], t0: int, t1: int, name: str) -> float:
+    """Device seconds of the operations whose own ``op_name`` path holds the
+    scope ``name``: per plane the union of their intervals in [t0, t1),
+    averaged over the planes."""
+    planes = trace.device_planes(events)
+    iv: dict[str, list] = {p: [] for p in planes}
+    for e in events:
+        if e["line"] == trace.OPS_LINE and name in e.get("own_scope", "").split("/"):
+            s, f = max(e["start_ns"], t0), min(e["start_ns"] + e["dur_ns"], t1)
+            if f > s:
+                iv[e["plane"]].append((s, f))
+    tot = sum(f - s for spans in iv.values() for s, f in trace._union(spans))
+    return tot / max(len(planes), 1) / 1e9
+
+
+def lane_numbers(red: dict, lanes: int, merge_s: float) -> dict:
+    """The lanes' fill, skew and per-dispatch costs (module docstring), from
+    the counters and span tables of the window before the profiler started
+    and, for the merge, the traced device seconds; ``None`` where the
+    program lacks the counter."""
+    c, spans = red["counters_untraced"], red["spans_untraced"]
+    n, kept = c["dispatches"], c["packets"]
+
+    def per_dispatch(span_name):
+        return 1e3 * spans[span_name][1] / n if span_name in spans and n else 0.0
+
+    return {
+        "lane_fill": 100.0 * kept / (kept + c["padded"]) if kept else None,
+        "lane_skew": lanes * c["lane_rows_max"] / kept
+        if kept and c["lane_rows_max"] else None,
+        "merge_ms_per_dispatch": 1e3 * merge_s / red["dispatches"]
+        if red["dispatches"] else None,
+        "partition_ms_per_dispatch": per_dispatch("octopus.partition"),
+        "scatter_ms_per_dispatch": per_dispatch("octopus.scatter"),
+    }
 
 
 def step_op_names(pipe) -> dict:
@@ -129,8 +183,12 @@ def traced_run(cell, seed: int, seconds: float, *, t_start: float,
     red["spans_traced"] = {**span_delta(v1, v2),
                            **span_delta(s1["spans"], s2["spans"])}
     red["counters_untraced"] = {k: s1[k] - s0[k] for k in COUNTERS}
+    t0, t1 = trace.window(events)
+    red["chip_busy"] = chip_busy(events, t0, t1)
+    if cell.config["lanes"] > 1:
+        red["lanes"] = lane_numbers(red, cell.config["lanes"],
+                                    named_seconds(events, t0, t1, MERGE_SCOPE))
     if keep:
-        t0, _ = trace.window(events)
         mods = sorted(e["start_ns"] for e in events
                       if e["line"] == trace.MODULES_LINE
                       and harness.STEP_PROGRAM in e["name"] and e["start_ns"] >= t0)
