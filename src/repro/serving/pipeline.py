@@ -57,6 +57,8 @@ Every host interval is a :class:`repro.runtime.span` (``octopus.pull``,
 ``.enqueue``, ``.wait``, ``.readback``, ``.feedback``, ``.counters``; the
 served ``step_masked`` wraps them in ``octopus.step``), totalled per name in
 ``PipelineStats.spans`` and written to the profiler's trace when it runs.
+What the host reads of a dispatch comes back in ONE device->host transfer:
+the :class:`HostPack`, a flat int32 array enqueued right behind the step.
 The step's device work runs under ``jax.named_scope`` names: ``track.promote``,
 ``track.merge`` (``track.fallback`` inside it), ``track.spill``,
 ``track.scrub``, ``drain``, ``engine.pkt`` and ``engine.flow``.
@@ -64,6 +66,7 @@ The step's device work runs under ``jax.named_scope`` names: ``track.promote``,
 from __future__ import annotations
 
 import itertools
+import math
 from dataclasses import dataclass, field
 from typing import Any, Iterable, NamedTuple, Optional, Sequence
 
@@ -176,17 +179,69 @@ class PipelineStepOutput(NamedTuple):
     ready_left: jax.Array  # () int32 — ready flows left undrained (max_ready)
 
 
-# the step counters, read back together in one transfer per dispatch
+# the step counters, read back in the host pack with step 6's arguments
 COUNTERS = ("new_flows", "evicted", "spilled", "promoted", "cold_walk",
             "fallback_slots", "ready_left")
 
 
-def _host_outputs(out: PipelineStepOutput) -> tuple[np.ndarray, ...]:
-    """What step 6 reads of one dispatch, on the host: packet actions,
-    drained mask and tuple ids, flow actions and classes."""
-    return (np.asarray(out.pkt_actions), np.asarray(out.drained.mask),
-            np.asarray(out.drained.tuple_id), np.asarray(out.flow_actions),
-            np.asarray(out.flow_cls))
+class HostPack(NamedTuple):
+    """What the host reads of one dispatch, as ONE flat int32 device array
+    (``flat``), and where each field lies in it: ``layout`` maps a field's
+    name to its offset, shape and dtype, static from the outputs' shapes."""
+
+    flat: jax.Array
+    layout: dict
+
+    def take(self, host: np.ndarray, name: str) -> np.ndarray:
+        """Field ``name`` of the pack's host copy ``host`` in its own dtype:
+        a view for 4-byte dtypes, a copy for narrower ones (the bool mask)."""
+        at, shape, dtype = self.layout[name]
+        part = host[at:at + math.prod(shape)].reshape(shape)
+        return part.view(dtype) if dtype.itemsize == 4 else part.astype(dtype)
+
+
+@jax.jit
+def _flatten(leaves: tuple) -> jax.Array:
+    # 4-byte leaves bit for bit, narrower ones (the bool mask) widened
+    return jnp.concatenate([
+        (lax.bitcast_convert_type(a, jnp.int32) if a.dtype.itemsize == 4
+         else a.astype(jnp.int32)).ravel() for a in leaves])
+
+
+def host_pack(out: PipelineStepOutput,
+              tuple_hash: Optional[jax.Array] = None) -> HostPack:
+    """Enqueue the host pack of one dispatch: ``pkt_actions``, the drained
+    ``mask`` and ``tuple_id``, ``flow_actions``, ``flow_cls`` and the
+    ``COUNTERS``, plus the batch's ``tuple_hash`` where the caller echoes it
+    (the single-lane steps: the served batch is a device upload).
+    ``flow_scores`` and the rest of ``drained`` stay on the device.  A
+    program of its own, built from whatever the step returned, so the pack
+    always holds exactly the outputs the step handed back."""
+    d = out.drained
+    leaves = {"pkt_actions": out.pkt_actions, "mask": d.mask,
+              "tuple_id": d.tuple_id, "flow_actions": out.flow_actions,
+              "flow_cls": out.flow_cls,
+              **{k: getattr(out, k) for k in COUNTERS}}
+    if tuple_hash is not None:
+        leaves["tuple_hash"] = tuple_hash
+    layout, at = {}, 0
+    for name, a in leaves.items():
+        layout[name] = (at, tuple(a.shape), np.dtype(a.dtype))
+        at += math.prod(a.shape)
+    return HostPack(_flatten(tuple(leaves.values())), layout)
+
+
+def _feedback_args(fields: dict, keep: Optional[np.ndarray] = None,
+                   tuple_hash: Optional[np.ndarray] = None) -> tuple:
+    """:meth:`OctopusPipeline._feedback`'s arguments from one dispatch's
+    host fields: the batch's hashes from the pack unless the caller holds
+    them (``tuple_hash``); of a padded bucket, the kept rows only."""
+    th = fields["tuple_hash"] if tuple_hash is None else tuple_hash
+    acts = fields["pkt_actions"]
+    if keep is not None:
+        th, acts = th[keep], acts[keep]
+    return (th, acts, fields["mask"], fields["tuple_id"],
+            fields["flow_actions"], fields["flow_cls"])
 
 
 class LatencyReservoir:
@@ -272,8 +327,10 @@ class PipelineStats:
     fallback_dispatches: int = 0  # dispatches whose merge took the fallback
     fallback_slots: int = 0  # table slots that took it, summed
     ready_left: int = 0  # ready flows left undrained after each drain, summed
+    host_reads: int = 0  # explicit device->host reads of step outputs: one
+    # per completed dispatch (the host pack), whatever its rounds
     host_s: float = 0.0  # host-side share: pull, enqueue (and partition),
-    # readback and feedback spans — not the counters' read
+    # readback and feedback spans — not the counters' slicing
     device_s: float = 0.0  # EXPOSED device wait (the ``octopus.wait`` span) —
     # what the host blocked on, not raw execution time; overlap shrinks it
     lat: LatencyReservoir = field(default_factory=LatencyReservoir)
@@ -285,7 +342,7 @@ class PipelineStats:
                         spilled: int = 0, promoted: int = 0,
                         cold_walk: int = 0, fallback_slots: int = 0,
                         ready_left: int = 0, padded: int = 0,
-                        lane_rows_max: int = 0,
+                        lane_rows_max: int = 0, host_reads: int = 0,
                         host_s: float = 0.0, device_s: float = 0.0) -> None:
         """Fold one timed dispatch (or fused multi-step chunk) into the
         counters.  ``packets`` must be the real packet count — callers that
@@ -308,6 +365,7 @@ class PipelineStats:
         self.ready_left += ready_left
         self.padded += padded
         self.lane_rows_max += lane_rows_max
+        self.host_reads += host_reads
         self.host_s += host_s
         self.device_s += device_s
         self.lat.add(dt * 1e6)  # one sample per timed region (us)
@@ -623,7 +681,7 @@ class OctopusPipeline:
                 lambda a: jnp.broadcast_to(a, (self.cfg.scan_len,) + a.shape),
                 self._zero_batch())
             _, out = self._chunk_fn(scratch, stacked)
-            jax.block_until_ready(out)
+            jax.block_until_ready(host_pack(out, stacked.tuple_hash))
         else:
             self._warm_step()
 
@@ -634,8 +692,9 @@ class OctopusPipeline:
         if self._step_warmed:
             return
         scratch = self._fresh_state()
-        _, out = self._step_fn(scratch, self._zero_batch())
-        jax.block_until_ready(out)
+        batch = self._zero_batch()
+        _, out = self._step_fn(scratch, batch)
+        jax.block_until_ready(host_pack(out, batch.tuple_hash))
         self._step_warmed = True
 
     def _zero_batch(self, n: Optional[int] = None) -> ft.PacketBatch:
@@ -666,50 +725,45 @@ class OctopusPipeline:
                               flow_cls[mask])
         return n_flows
 
-    def _complete(self, out: PipelineStepOutput, readback, *, host_s: float,
-                  rounds: Sequence[dict] = (), **record) -> dict:
+    def _complete(self, out: PipelineStepOutput, pack: HostPack, rows, *,
+                  host_s: float, rounds: Sequence[dict] = (),
+                  **record) -> PipelineStepOutput:
         """The host's half of one enqueued dispatch, after the enqueue: wait
-        for ``out``, read back what step 6 needs (``readback()`` returns one
-        :meth:`_feedback` argument tuple per step), feed the rule table, read
-        every counter back in one transfer and record the dispatch.
-        ``host_s`` is the host time already spent on it (pull, partition,
-        enqueue); ``rounds`` holds the counters of earlier merge-only rounds
-        (sharded overflow).  The counters' read is timed apart from
-        ``host_s``.  Returns the counters, summed over steps and rounds."""
+        for ``out``, then read the host ``pack`` and the outputs of earlier
+        merge-only ``rounds`` (sharded overflow) back in ONE transfer, slice
+        step 6's arguments (``rows(fields, rounds)`` returns one
+        :meth:`_feedback` argument tuple per step), feed the rule table,
+        slice the counters and record the dispatch.  ``host_s`` is the host
+        time already spent on it (pull, partition, enqueue); the counters'
+        slicing is timed apart from it.  Returns ``out`` with its host-read
+        fields replaced by numpy views of that one host copy, the counters
+        summed over rounds."""
         st = self.stats
         with span("octopus.wait", st) as wait:
             jax.block_until_ready(out)
         with span("octopus.readback", st) as rb:
-            steps = readback()
+            host, rounds = jax.device_get((pack.flat, list(rounds)))
+            fields = {k: pack.take(host, k) for k in pack.layout
+                      if k not in COUNTERS}
+            steps = rows(fields, rounds)
         with span("octopus.feedback", st) as fb:
             flows = sum(self._feedback(*args) for args in steps)
         with span("octopus.counters", st):
-            got = jax.device_get([{k: getattr(out, k) for k in COUNTERS},
-                                  *rounds])
-        counters = {k: int(sum(np.sum(c[k]) for c in got if k in c))
-                    for k in COUNTERS}
+            for k in COUNTERS:
+                fields[k] = np.asarray(sum((c[k].sum(dtype=np.int32)
+                                            for c in rounds if k in c),
+                                           pack.take(host, k)))
+            counters = {k: int(fields[k].sum()) for k in COUNTERS}
         host_s += rb.s + fb.s
         st.record_dispatch(host_s + wait.s, flows=flows, host_s=host_s,
-                           device_s=wait.s, **counters, **record)
-        return counters
-
-    def _chunk_rows(self, batches: Sequence[ft.PacketBatch],
-                    out: PipelineStepOutput) -> list[tuple]:
-        """Step 6's arguments for one fused chunk (stacked outputs, leading
-        step axis), one tuple per step in step order so later verdicts
-        overwrite earlier — shared by the single-lane and sharded chunked
-        dispatches.  The hashes come from the host-resident ``batches``;
-        reading them back from the stacked device arrays would add a
-        device->host transfer per chunk."""
-        hashes = np.stack([np.asarray(b.tuple_hash) for b in batches])
-        return list(zip(hashes, *_host_outputs(out)))
-
-    def _masked_rows(self, packets: ft.PacketBatch, keep: np.ndarray,
-                     out: PipelineStepOutput) -> list[tuple]:
-        """Step 6's arguments for one padded bucket: kept rows only."""
-        pkt_actions, *flows = _host_outputs(out)
-        return [(np.asarray(packets.tuple_hash)[keep], pkt_actions[keep],
-                 *flows)]
+                           device_s=wait.s, host_reads=1, **counters,
+                           **record)
+        return out._replace(
+            pkt_actions=fields["pkt_actions"],
+            drained=out.drained._replace(mask=fields["mask"],
+                                         tuple_id=fields["tuple_id"]),
+            flow_actions=fields["flow_actions"], flow_cls=fields["flow_cls"],
+            **{k: fields[k] for k in COUNTERS})
 
     def _dispatch_step(self, packets: ft.PacketBatch) -> InflightDispatch:
         """Enqueue one microbatch (steps 2-5) without blocking — JAX async
@@ -719,17 +773,16 @@ class OctopusPipeline:
         n = self._check_batch(packets)
         with span("octopus.enqueue", self.stats) as enq:
             self.state, out = self._step_fn(self.state, packets)
+            pack = host_pack(out, packets.tuple_hash)
         self._step_warmed = True  # compiled now, whatever the entry path
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
             # block on the outputs only: under overlap the state has already
             # been donated to the next enqueued dispatch (same computation,
             # so `out` ready implies the state update finished too)
-            self._complete(
-                out, lambda: [(np.asarray(packets.tuple_hash),
-                               *_host_outputs(out))],
-                host_s=enq.s + host_extra_s, packets=n)
-            return out
+            return self._complete(out, pack,
+                                  lambda f, _: [_feedback_args(f)],
+                                  host_s=enq.s + host_extra_s, packets=n)
 
         return InflightDispatch(finish, steps=1, packets=n)
 
@@ -756,9 +809,9 @@ class OctopusPipeline:
         if bucket in self._warm_buckets:
             return
         scratch = self._fresh_state()
-        _, out = self._masked_fn(scratch, self._zero_batch(bucket),
-                                 jnp.zeros((bucket,), bool))
-        jax.block_until_ready(out)
+        batch = self._zero_batch(bucket)
+        _, out = self._masked_fn(scratch, batch, jnp.zeros((bucket,), bool))
+        jax.block_until_ready(host_pack(out, batch.tuple_hash))
         self._warm_buckets.add(bucket)
 
     def step_masked(self, packets: ft.PacketBatch,
@@ -769,8 +822,12 @@ class OctopusPipeline:
         sharded lane's skew rows).  The batch may be any pre-warmed bucket
         size; it is NOT tied to ``cfg.batch_size``.  The whole call is the
         ``octopus.step`` span, tagged with this dispatch's number (the
-        ``dispatches`` count before it) and its bucket."""
-        bucket = int(np.asarray(packets.ts).shape[0])
+        ``dispatches`` count before it) and its bucket.  Its one device->host
+        read is the host pack: the returned output's host-read fields
+        (``pkt_actions``, ``drained.mask``/``tuple_id``, ``flow_actions``,
+        ``flow_cls``, the counters) are numpy, ``flow_scores`` and the rest
+        of ``drained`` stay on the device."""
+        bucket = np.shape(packets.ts)[0]
         k = np.asarray(keep)
         if k.shape != (bucket,):
             raise ValueError(f"keep must have shape ({bucket},), got {k.shape}")
@@ -780,10 +837,11 @@ class OctopusPipeline:
             with span("octopus.enqueue", st) as enq:
                 self.state, out = self._masked_fn(self.state, packets,
                                                   jnp.asarray(k))
+                pack = host_pack(out, packets.tuple_hash)
             self._warm_buckets.add(bucket)  # compiled now, whatever the path
-            self._complete(out, lambda: self._masked_rows(packets, k, out),
-                           host_s=enq.s, packets=n, padded=bucket - n)
-        return out
+            return self._complete(out, pack,
+                                  lambda f, _: [_feedback_args(f, k)],
+                                  host_s=enq.s, packets=n, padded=bucket - n)
 
     def _dispatch_chunk(self, batches: Sequence[ft.PacketBatch]
                         ) -> InflightDispatch:
@@ -802,12 +860,16 @@ class OctopusPipeline:
             stacked = jax.tree_util.tree_map(lambda *xs: jnp.stack(xs),
                                              *batches)
             self.state, out = self._chunk_fn(self.state, stacked)
+            pack = host_pack(out, stacked.tuple_hash)
         n = L * self.cfg.batch_size
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
-            self._complete(out, lambda: self._chunk_rows(batches, out),
-                           host_s=enq.s + host_extra_s, packets=n, steps=L)
-            return out
+            # one tuple per step (leading step axis), in step order, so
+            # later verdicts overwrite earlier
+            return self._complete(out, pack,
+                                  lambda f, _: list(zip(*_feedback_args(f))),
+                                  host_s=enq.s + host_extra_s, packets=n,
+                                  steps=L)
 
         return InflightDispatch(finish, steps=L, packets=n)
 

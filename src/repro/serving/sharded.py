@@ -79,7 +79,8 @@ from repro.serving.pipeline import (
     OctopusPipeline,
     PipelineConfig,
     PipelineStepOutput,
-    _host_outputs,
+    _feedback_args,
+    host_pack,
 )
 
 LANE_BACKENDS = ("vmap", "shard_map")
@@ -309,34 +310,32 @@ class ShardedOctopusPipeline(OctopusPipeline):
             last = rounds[-1]
             self.state, out = self._step_fn(self.state, last.shards,
                                             last.keep, last.src)
+            pack = host_pack(out)
         self._step_warmed = True
-        pkt_actions = []  # the verdicts in batch order, once read back
+        # what the overlay reads of each round, in the pack's transfer: the
+        # merge rounds' counters and verdicts, every round's lane rows
+        earlier = [{**c, "pkt_actions": a, "keep": sb.keep, "src": sb.src}
+                   for sb, c, a in merge_outs]
+        if earlier:
+            earlier.append({"keep": last.keep, "src": last.src})
 
-        def readback() -> list[tuple]:
-            acts, *flows = _host_outputs(out)
-            if merge_outs:  # overlay earlier rounds' packet verdicts
+        def readback(f: dict, got: list) -> list[tuple]:
+            if got:  # overlay earlier rounds' packet verdicts
                 with span("octopus.scatter", st):
                     merged = np.zeros((n,), np.int32)
-                    for sb, _, a in merge_outs:
-                        k = np.asarray(sb.keep)
-                        merged[np.asarray(sb.src)[k]] = np.asarray(a)[k]
-                    pos = np.asarray(last.src)[np.asarray(last.keep)]
-                    merged[pos] = acts[pos]
-                    acts = merged
-            pkt_actions.append(acts)
-            return [(np.asarray(packets.tuple_hash), acts, *flows)]
+                    for r in got[:-1]:
+                        k = r["keep"]
+                        merged[r["src"][k]] = r["pkt_actions"][k]
+                    pos = got[-1]["src"][got[-1]["keep"]]
+                    merged[pos] = f["pkt_actions"][pos]
+                    f["pkt_actions"] = merged
+            return [_feedback_args(f, tuple_hash=np.asarray(packets.tuple_hash))]
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
-            counters = self._complete(
-                out, readback, host_s=part.s + enq.s + host_extra_s,
-                rounds=[c for _, c, _ in merge_outs], packets=n,
-                dispatches=len(rounds), padded=self._padded_rows(rounds),
-                lane_rows_max=fullest)
-            if not merge_outs:
-                return out
-            return out._replace(
-                pkt_actions=jnp.asarray(pkt_actions[0]),
-                **{k: jnp.int32(v) for k, v in counters.items()})
+            return self._complete(
+                out, pack, readback, host_s=part.s + enq.s + host_extra_s,
+                rounds=earlier, packets=n, dispatches=len(rounds),
+                padded=self._padded_rows(rounds), lane_rows_max=fullest)
 
         return InflightDispatch(finish, steps=1, packets=n)
 
@@ -362,8 +361,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
             def finish(host_extra_s: float) -> PipelineStepOutput:
                 inner.add_host_time(host_extra_s)
                 out = inner.wait()  # records the dispatch in stats itself
-                return jax.tree_util.tree_map(
-                    lambda a: jnp.asarray(a)[None], out)
+                return jax.tree_util.tree_map(lambda a: a[None], out)
 
             return InflightDispatch(finish, steps=1,
                                     packets=self.cfg.batch_size)
@@ -378,16 +376,20 @@ class ShardedOctopusPipeline(OctopusPipeline):
                 jax.tree_util.tree_map(lambda *xs: jnp.stack(xs), *leaves)
                 for leaves in zip(*parts))
             self.state, out = self._chunk_fn(self.state, shards, keep, src)
+            pack = host_pack(out)
         n = L * self.cfg.batch_size
         # parts holds one single-round partition PER STEP — padding is per
         # step, not one multi-round step's worth
         padded = sum(self._padded_rows([p]) for p in parts)
+        # the hashes come from the host-resident batches, one row per step
+        hashes = np.stack([np.asarray(b.tuple_hash) for b in batches])
 
         def finish(host_extra_s: float) -> PipelineStepOutput:
-            self._complete(out, lambda: self._chunk_rows(batches, out),
-                           host_s=part.s + enq.s + host_extra_s, packets=n,
-                           steps=L, padded=padded, lane_rows_max=fullest)
-            return out
+            return self._complete(
+                out, pack,
+                lambda f, _: list(zip(*_feedback_args(f, tuple_hash=hashes))),
+                host_s=part.s + enq.s + host_extra_s, packets=n, steps=L,
+                padded=padded, lane_rows_max=fullest)
 
         return InflightDispatch(finish, steps=L, packets=n)
 
@@ -412,7 +414,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
         scratch = self._fresh_state()
         zb = self._zero_parts(bucket)
         _, out = self._masked_fn(scratch, zb.shards, zb.keep, zb.src)
-        jax.block_until_ready(out)
+        jax.block_until_ready(host_pack(out))
         self._warm_buckets.add(bucket)
 
     def step_masked(self, packets: ft.PacketBatch,
@@ -420,8 +422,9 @@ class ShardedOctopusPipeline(OctopusPipeline):
         """One padded request batch through all lanes.  The keep mask is
         folded into the hash partition (padding rows land in no lane), and
         the partition runs at full bucket capacity — always one round, so a
-        bucket compiles exactly one entry whatever the skew."""
-        bucket = int(np.asarray(packets.ts).shape[0])
+        bucket compiles exactly one entry whatever the skew.  The pack leaves
+        the hashes out: the partition has read them to the host already."""
+        bucket = np.shape(packets.ts)[0]
         k = np.asarray(keep, bool)
         if k.shape != (bucket,):
             raise ValueError(f"keep must have shape ({bucket},), got {k.shape}")
@@ -434,12 +437,13 @@ class ShardedOctopusPipeline(OctopusPipeline):
             with span("octopus.enqueue", st) as enq:
                 self.state, out = self._masked_fn(self.state, sb.shards,
                                                   sb.keep, sb.src)
+                pack = host_pack(out)
             self._warm_buckets.add(bucket)
-            self._complete(out, lambda: self._masked_rows(packets, k, out),
-                           host_s=part.s + enq.s, packets=n,
-                           padded=self.num_shards * bucket - n,
-                           lane_rows_max=fullest)
-        return out
+            hashes = np.asarray(packets.tuple_hash)
+            return self._complete(
+                out, pack, lambda f, _: [_feedback_args(f, k, hashes)],
+                host_s=part.s + enq.s, packets=n,
+                padded=self.num_shards * bucket - n, lane_rows_max=fullest)
 
     def warmup(self) -> None:
         """Compile the dispatch paths ``run`` will use on throwaway state:
@@ -454,13 +458,13 @@ class ShardedOctopusPipeline(OctopusPipeline):
                 zb)
             _, out = self._chunk_fn(scratch, stacked.shards, stacked.keep,
                                     stacked.src)
-            jax.block_until_ready(out)
+            jax.block_until_ready(host_pack(out))
         else:
             if self.lane_batch < self.cfg.batch_size:
                 scratch, *_ = self._merge_fn(scratch, zb.shards, zb.keep)
                 self._merge_warmed = True
             _, out = self._step_fn(scratch, zb.shards, zb.keep, zb.src)
-            jax.block_until_ready(out)
+            jax.block_until_ready(host_pack(out))
             self._step_warmed = True
 
     def _warm_step(self) -> None:
@@ -472,7 +476,7 @@ class ShardedOctopusPipeline(OctopusPipeline):
             scratch, *_ = self._merge_fn(scratch, zb.shards, zb.keep)
             self._merge_warmed = True
         _, out = self._step_fn(scratch, zb.shards, zb.keep, zb.src)
-        jax.block_until_ready(out)
+        jax.block_until_ready(host_pack(out))
         self._step_warmed = True
 
     # ------------------------------------------------------------- placement

@@ -15,7 +15,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
-from jax.sharding import Mesh, NamedSharding, SingleDeviceSharding
+from jax.sharding import Mesh, NamedSharding, PartitionSpec, SingleDeviceSharding
 
 from repro.distributed import sharding as shd
 from repro.kernels.arype_matmul import arype_matmul
@@ -168,3 +168,26 @@ def test_four_lane_step_compiles(four_chips, monkeypatch):
     for line in collectives:
         assert not wide.search(line.split("metadata=", 1)[0]), line
         assert "/lanes.merge/" in line, line
+
+
+@pytest.mark.parametrize("lanes", [1, 4])
+def test_host_pack_compiles_as_one_array(one_chip, four_chips, lanes):
+    """The host pack of the served step's outputs (``ids-cnn`` at bucket
+    256 with the echoed hashes; ``ids-cnn-x4`` at 1024, its drained rows
+    split over the lanes' chips) compiles to ONE array that every chip
+    holds whole, so its read-back is a single copy from one chip."""
+    from repro.serving.pipeline import COUNTERS, _flatten
+
+    bucket, rows = (256, 64) if lanes == 1 else (1024, 256)
+    whole = one_chip if lanes == 1 else NamedSharding(four_chips, PartitionSpec())
+    split = one_chip if lanes == 1 else NamedSharding(four_chips, shd.lanes_spec())
+    leaves = [_sds(whole, (bucket,)), _sds(split, (rows,), jnp.bool_),
+              *(_sds(split, (rows,)) for _ in range(3)),
+              *(_sds(whole, ()) for _ in COUNTERS)]
+    if lanes == 1:
+        leaves.append(_sds(one_chip, (bucket,)))  # the echoed tuple hashes
+    compiled = _flatten.lower(tuple(leaves)).compile()
+    out = compiled.output_shardings
+    assert out.is_fully_replicated and len(out.device_set) == lanes
+    assert compiled.out_info.shape == (bucket * (2 if lanes == 1 else 1)
+                                       + 4 * rows + len(COUNTERS),)

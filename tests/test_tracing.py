@@ -191,6 +191,22 @@ def test_sharded_spans_partition_scatter_and_rounds(fake_clock, mlp_params,
     assert s.new_flows > 0
 
 
+@pytest.mark.parametrize("num_shards", [0, 2])
+def test_one_readback_span_per_dispatch(mlp_params, cnn_params, num_shards):
+    """The served step reads its host pack once: one ``octopus.readback``
+    per dispatch, counted in ``host_reads``, and the counters' slicing
+    still its own ``octopus.counters`` span once per dispatch."""
+    pipe = make_pipeline(mlp_params, cnn_params, num_shards=num_shards)
+    pipe.warm_bucket(16)
+    gen = traffic(16, seed=8)
+    for n in (16, 9, 12, 16):
+        pipe.step_masked(gen.next_batch(), np.arange(16) < n)
+    s, sp = pipe.stats, pipe.stats.spans
+    assert s.dispatches == 4
+    assert sp["octopus.readback"].count == s.dispatches == s.host_reads
+    assert sp["octopus.counters"].count == s.dispatches
+
+
 # -------------------------------------------------- the service's host spans
 
 @async_test

@@ -38,7 +38,8 @@ from bench import harness, scopes, trace  # noqa: E402
 
 # pipeline counters read beside the harness's (0 where the program lacks them)
 COUNTERS = ("dispatches", "fallback_dispatches", "fallback_slots", "ready_left",
-            "spilled", "promoted", "cold_walk", "packets", "padded", "lane_rows_max")
+            "spilled", "promoted", "cold_walk", "packets", "padded", "lane_rows_max",
+            "host_reads")
 # the sharded step's merge across lanes (serving/sharded.py)
 MERGE_SCOPE = "lanes.merge"
 KEEP_DISPATCHES = 3
